@@ -135,6 +135,9 @@ def main(argv=None) -> int:
                          "--smoke so the canary never clobbers the tracked "
                          "full-run trajectory)")
     args = ap.parse_args(argv)
+    from repro.caches import enable_compile_cache
+
+    enable_compile_cache()
     if args.json is None:
         args.json = "BENCH_apsp_smoke.json" if args.smoke else "BENCH_apsp.json"
 

@@ -14,7 +14,6 @@ import jax
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro import compat
 from repro.configs import get_arch
 from repro.launch.builders import build_cell
 from repro.launch.mesh import make_production_mesh
@@ -25,7 +24,7 @@ def diag(arch_id, shape_id, top=20):
     arch = get_arch(arch_id)
     cell = arch.cells[shape_id]
     mesh = make_production_mesh(multi_pod=False)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         dr = build_cell(arch, cell, mesh)
         c = jax.jit(dr.fn, in_shardings=dr.in_shardings,
                     out_shardings=dr.out_shardings).lower(*dr.args).compile()

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import contextlib
 from dataclasses import dataclass, field
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, List, Optional, Set, Tuple
 
 import jax
 import numpy as np
@@ -42,9 +42,13 @@ class KernelCall:
     num_scalar_prefetch: int
     dimension_semantics: Optional[Tuple[str, ...]]
     interpret: bool
+    scratch_shapes: List[Any] = field(default_factory=list)  # VMEM scratch
     operands: Tuple[np.ndarray, ...] = ()  # concrete, prefetch-first
     results: Tuple[np.ndarray, ...] = ()   # simulated output leaves
     errors: List[str] = field(default_factory=list)  # simulation-time bounds
+    # (scratch index, writer step, reader step): a scratch value read at a
+    # later grid step than the one that wrote it (steps in C order)
+    carries: Set[Tuple[int, int, int]] = field(default_factory=set)
 
     @property
     def prefetch(self) -> Tuple[np.ndarray, ...]:
@@ -81,6 +85,7 @@ def intercept_pallas_calls(executor: Optional[Callable] = None):
         grid_spec=None,
         interpret=False,
         compiler_params=None,
+        scratch_shapes=(),
         **_kw,
     ):
         g, isp, osp, nsp = grid, in_specs, out_specs, 0
@@ -89,6 +94,7 @@ def intercept_pallas_calls(executor: Optional[Callable] = None):
             isp = grid_spec.in_specs
             osp = grid_spec.out_specs
             nsp = int(getattr(grid_spec, "num_scalar_prefetch", 0) or 0)
+            scratch_shapes = getattr(grid_spec, "scratch_shapes", ()) or ()
         out_leaves, out_tree = jax.tree_util.tree_flatten(out_shape)
         osp_leaves = jax.tree_util.tree_leaves(osp, is_leaf=_is_spec)
         isp_leaves = jax.tree_util.tree_leaves(isp, is_leaf=_is_spec)
@@ -103,6 +109,7 @@ def intercept_pallas_calls(executor: Optional[Callable] = None):
             num_scalar_prefetch=nsp,
             dimension_semantics=tuple(sem) if sem is not None else None,
             interpret=bool(interpret),
+            scratch_shapes=list(scratch_shapes),
         )
         calls.append(call)
 

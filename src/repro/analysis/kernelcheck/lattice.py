@@ -312,9 +312,11 @@ def default_cases() -> List[Case]:
         _fw_block_case("fw_block/single", 8, seed=9),
         _fw_block_case("fw_block/batch", 8, t=3, seed=10),
         _fw_block_case("fw_block_pred/batch", 8, t=2, pred=True, seed=11),
-        # -- fw_round: first and last pivot, batched --
+        # -- fw_round: first and last pivot, batched, a multi-tile j sweep
+        #    (three 128-wide column tiles carry the col' scratch) --
         case_for_fw_round_params(8, 16, o=0, seed=12),
         case_for_fw_round_params(8, 16, g=2, seed=13),
+        case_for_fw_round_params(64, 384, o=128, g=2, seed=17),
         # -- row_close: gather incl. row n-1 + duplicates, track, unaligned --
         case_for_row_close_params(dict(bn=128, bk=8, kc=8), 4, 16, seed=14),
         case_for_row_close_params(dict(bn=128, bk=8, kc=8), 4, 16, track=True,

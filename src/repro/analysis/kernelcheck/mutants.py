@@ -25,6 +25,12 @@ Corpus (kind → seeded defect):
   partial accumulation).
 * ``padding``  — operands padded with ``0.0`` instead of the semiring
   zero on a non-aligned shape: padded candidates win and corrupt columns.
+* ``bounds``   — an in-body ``pl.ds`` window that starts one lane past the
+  block's first window and so runs off the block's end.
+* ``race``     — the real ``fw_round`` builder with its column axis j
+  declared ``"parallel"``: the col' scratch filled at j = 0 is carried
+  across a parallel axis (the scratch-carry theorem; no output tile is
+  written twice, so the write-race theorem alone would pass it).
 """
 
 from __future__ import annotations
@@ -39,12 +45,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.semiring import TROPICAL, Semiring
-from repro.kernels.minplus import _minplus_body, _pad, _rup
+from repro.kernels.minplus import _fold, _pad, _rup
 from repro.kernels.ref import minplus_ref
 
-from .lattice import Case, _mat
+from .lattice import Case, _mat, case_for_fw_round_params
 
 __all__ = ["Mutant", "mutant_cases", "control_case"]
 
@@ -53,6 +58,7 @@ __all__ = ["Mutant", "mutant_cases", "control_case"]
 class Mutant:
     case: Case
     expect: str     # the Problem kind that must appear
+    match: str = ""  # ...with this text in its message
 
 
 def _mini_minplus(
@@ -61,6 +67,7 @@ def _mini_minplus(
     out_map: Optional[Callable] = None,
     init: str = "gate",          # "gate" | "none" | "always"
     fill: Optional[float] = None,
+    x_shift: int = 0,
 ):
     """A minimal, knowingly-mutable tiled ⊕⊗ builder (minplus arithmetic)."""
     fill = sr.zero if fill is None else fill
@@ -78,15 +85,18 @@ def _mini_minplus(
             pl.when(pl.program_id(2) == 0)(_init)
         elif init == "always":
             _init()
-        acc, _ = _minplus_body(
-            x_ref[...], y_ref[...], kc, pl.program_id(2) * bk,
-            z_ref[...], None, sr,
-        )
-        z_ref[...] = acc
+        if x_shift:
+            # reads the x window one lane late: the last one leaves the block
+            z_ref[...] = sr.add(
+                z_ref[...],
+                sr.mul(x_ref[:, pl.ds(bk - kc + x_shift, kc)][:, :1],
+                       y_ref[pl.ds(0, 1), :]),
+            )
+        _fold(x_ref, y_ref, z_ref, k_base=pl.program_id(2) * bk, kc=kc, sr=sr)
 
     params = {}
     if semantics is not None:
-        params["compiler_params"] = tpu_compiler_params(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=semantics
         )
     zp = pl.pallas_call(
@@ -109,38 +119,65 @@ def _mini_gather(d, rows, *, bn, bk, kc, sr, shift: int = 0):
     n = d.shape[-1]
     r = rows.shape[0]
     bn_ = min(bn, _rup(n, 128))
+    kc = min(kc, _rup(n, 8))
     bk_ = min(_rup(bk, kc), _rup(n, kc))
-    dx = _pad(d, 1, bk_, sr.zero)
     dy = _pad(d, bk_, bn_, sr.zero)
     kp, np_ = dy.shape
+    dx = _pad(d, 1, bk_, sr.zero).reshape(n, 1, kp)
 
     def kern(rows_ref, x_ref, y_ref, z_ref):
         @pl.when(pl.program_id(2) == 0)
         def _init():
             z_ref[...] = jnp.full_like(z_ref[...], sr.zero)
 
-        acc, _ = _minplus_body(
-            x_ref[...], y_ref[...], kc, pl.program_id(2) * bk_,
-            z_ref[...], None, sr,
-        )
-        z_ref[...] = acc
+        _fold(x_ref, y_ref, z_ref, k_base=pl.program_id(2) * bk_, kc=kc,
+              sr=sr)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(r, np_ // bn_, kp // bk_),
         in_specs=[
-            pl.BlockSpec((1, bk_), lambda i, j, kk, rows: (rows[i] + shift, kk)),
+            pl.BlockSpec((None, 1, bk_),
+                         lambda i, j, kk, rows: (rows[i] + shift, 0, kk)),
             pl.BlockSpec((bk_, bn_), lambda i, j, kk, rows: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((1, bn_), lambda i, j, kk, rows: (i, j)),
+        out_specs=pl.BlockSpec((None, 1, bn_),
+                               lambda i, j, kk, rows: (i, 0, j)),
     )
     zp = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, np_), d.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, 1, np_), d.dtype),
         interpret=False,
     )(rows.astype(jnp.int32), dx, dy)
-    return zp[:, :n]
+    return zp[:, 0, :n]
+
+
+def _fw_round_semantics(d, o, *, semantics: Tuple[str, ...], **kw):
+    """The real ``fw_round`` builder, its row-stripe grid declared with
+    ``semantics`` (the pivot closure's own grid keeps its declaration)."""
+    from repro.kernels.fw_round import fw_round_pallas
+
+    real = pltpu.CompilerParams
+
+    def params(**p):
+        if len(p.get("dimension_semantics", ())) == len(semantics):
+            p["dimension_semantics"] = semantics
+        return real(**p)
+
+    pltpu.CompilerParams = params
+    try:
+        return fw_round_pallas.__wrapped__(d, o, **kw)
+    finally:
+        pltpu.CompilerParams = real
+
+
+def _fw_round_case(name: str, seed: int, semantics: Tuple[str, ...]) -> Case:
+    # n = 3 column tiles of 128, so the j sweep carries the scratch
+    case = case_for_fw_round_params(64, 384, seed=seed)
+    case.name, case.builder = name, "(mutant)"
+    case.builder_fn = functools.partial(_fw_round_semantics, semantics=semantics)
+    return case
 
 
 def _mini_case(
@@ -215,5 +252,14 @@ def mutant_cases() -> List[Mutant]:
         Mutant(
             _gather_case("mutant/unchecked-gather", 107, shift=1),
             expect="bounds",
+        ),
+        Mutant(
+            _mini_case("mutant/overrun-window", 108, x_shift=1),
+            expect="bounds",
+        ),
+        Mutant(
+            _fw_round_case("mutant/fw-round-parallel-j", 109,
+                           ("parallel", "parallel", "parallel")),
+            expect="race", match="scratch 0",
         ),
     ]

@@ -20,7 +20,19 @@ Every tile — input, output, and the scalar-prefetch ``rows[i]`` gather —
 is bounds-checked against its operand's (padded) extent *before* the body
 runs; a violating grid point records the violation and is skipped (numpy
 would silently clip the view, masking the bug with a shape error or, worse,
-wrong data).
+wrong data).  A ``None`` (squeezed) block dim is a unit tile whose axis the
+kernel does not see.
+
+Kernel bodies index their refs with ``pl.ds`` windows and walk them with
+``jax.lax.fori_loop``; while a body runs, the loop is a plain Python loop
+and ``pl.multiple_of`` the identity, so every window offset is a concrete
+int and every ``pl.ds`` a numpy slice.  A ref slice that leaves its block
+raises, like Mosaic's bounds check.  VMEM scratch buffers are allocated once
+per call (seeded with the canary) and persist across grid points, as the
+TPU keeps them across the sequential walk.  Each scratch element remembers
+the grid step that last wrote it, and every read of a value written at an
+earlier step is recorded in ``call.carries``, for the scratch-carry theorem
+in ``verify``.
 """
 
 from __future__ import annotations
@@ -28,6 +40,7 @@ from __future__ import annotations
 import contextlib
 from typing import List, Sequence, Tuple
 
+import jax
 import numpy as np
 from jax.experimental import pallas as _pallas
 
@@ -55,16 +68,70 @@ class _Ref:
         return self.a.dtype
 
     def __getitem__(self, idx):
-        return self.a[idx]
+        return self.a[self._np_index(idx)]
 
     def __setitem__(self, idx, val):
-        self.a[idx] = np.asarray(val)
+        self.a[self._np_index(idx)] = np.asarray(val)
+
+    def _np_index(self, idx):
+        """Translate ``pl.ds`` windows to numpy slices, refusing any window
+        that leaves the block (numpy would clip it silently)."""
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        out = []
+        for d, i in enumerate(idx):
+            if isinstance(i, _pallas.Slice):
+                lo, size = int(i.start), int(i.size)
+                if lo < 0 or lo + size * int(i.stride) > self.a.shape[d]:
+                    raise IndexError(
+                        f"ref window [{lo}, {lo + size}) outside block dim "
+                        f"{d} of extent {self.a.shape[d]}"
+                    )
+                i = slice(lo, lo + size * int(i.stride), int(i.stride))
+            elif not isinstance(i, (slice, type(Ellipsis))):
+                i = int(i)
+            out.append(i)
+        return tuple(out)
+
+
+class _Scratch(_Ref):
+    """A VMEM scratch ref that records values carried between grid steps."""
+
+    __slots__ = ("writer", "step", "index", "carries")
+
+    def __init__(self, a: np.ndarray, index: int, carries: set):
+        super().__init__(a)
+        self.writer = np.full(a.shape, -1, np.int64)   # step that last wrote
+        self.step = -1                                 # step now running
+        self.index = index
+        self.carries = carries
+
+    def __getitem__(self, idx):
+        sl = self._np_index(idx)
+        for w in np.unique(self.writer[sl]):
+            if 0 <= w < self.step:
+                self.carries.add((self.index, int(w), self.step))
+        return self.a[sl]
+
+    def __setitem__(self, idx, val):
+        sl = self._np_index(idx)
+        self.a[sl] = np.asarray(val)
+        self.writer[sl] = self.step
+
+
+def _fori_loop(lower, upper, body, init, **_kw):
+    """``jax.lax.fori_loop`` as a Python loop over concrete indices."""
+    carry = init
+    for i in range(int(lower), int(upper)):
+        carry = body(i, carry)
+    return carry
 
 
 @contextlib.contextmanager
 def _patched_pl(point: Tuple[int, ...], grid: Tuple[int, ...]):
-    """Bind ``pl.program_id``/``num_programs``/``when`` to one grid point."""
-    saved = (_pallas.program_id, _pallas.num_programs, _pallas.when)
+    """Bind ``pl.program_id``/``num_programs``/``when`` to one grid point,
+    and run in-body loops eagerly (see the module docstring)."""
+    saved = (_pallas.program_id, _pallas.num_programs, _pallas.when,
+             _pallas.multiple_of, jax.lax.fori_loop)
 
     def when(cond):
         def deco(fn):
@@ -77,10 +144,13 @@ def _patched_pl(point: Tuple[int, ...], grid: Tuple[int, ...]):
     _pallas.program_id = lambda axis: point[axis]
     _pallas.num_programs = lambda axis: grid[axis]
     _pallas.when = when
+    _pallas.multiple_of = lambda x, _m: x
+    jax.lax.fori_loop = _fori_loop
     try:
         yield
     finally:
-        _pallas.program_id, _pallas.num_programs, _pallas.when = saved
+        (_pallas.program_id, _pallas.num_programs, _pallas.when,
+         _pallas.multiple_of, jax.lax.fori_loop) = saved
 
 
 def block_index(spec, point: Sequence[int], prefetch) -> Tuple[int, ...]:
@@ -102,17 +172,19 @@ def tile_slices(
     """Element slices of one tile, recording any out-of-bounds dimension.
 
     Blocked-mode semantics: the index map returns *block* indices, the tile
-    spans ``[idx*bs, (idx+1)*bs)`` per dimension.
+    spans ``[idx*bs, (idx+1)*bs)`` per dimension; a ``None`` (squeezed) dim
+    is a unit tile indexed by an int, so the view drops that axis.
     """
     sl = []
     for d, (i, bs, n) in enumerate(zip(idx, block_shape, extent)):
-        lo, hi = i * bs, (i + 1) * bs
+        size = 1 if bs is None else bs
+        lo, hi = i * size, (i + 1) * size
         if lo < 0 or hi > n:
             errors.append(
                 f"bounds: {where}: dim {d} tile [{lo}, {hi}) outside the "
                 f"operand extent {n} (block index {i} x block {bs})"
             )
-        sl.append(slice(lo, hi))
+        sl.append(lo if bs is None else slice(lo, hi))
     return tuple(sl)
 
 
@@ -140,26 +212,30 @@ def simulate(call: KernelCall) -> List[np.ndarray]:
         )
         return outs
 
-    for point in np.ndindex(*call.grid):
+    scratch = [_Scratch(_canary(s.shape, s.dtype), i, call.carries)
+               for i, s in enumerate(call.scratch_shapes)]
+    for step, point in enumerate(np.ndindex(*call.grid)):
+        for ref in scratch:
+            ref.step = step
         point_errors: List[str] = []
-        refs = [_Ref(p) for p in prefetch]
-        for ai, (arr, spec) in enumerate(zip(ins, call.in_specs)):
+        views = []
+        operands = [(a, s, f"input {i}") for i, (a, s) in
+                    enumerate(zip(ins, call.in_specs))]
+        operands += [(o, s, f"output {i}") for i, (o, s) in
+                     enumerate(zip(outs, call.out_specs))]
+        for arr, spec, what in operands:
             idx = block_index(spec, point, prefetch)
-            sl = tile_slices(
+            views.append((arr, tile_slices(
                 idx, tuple(spec.block_shape), arr.shape,
-                where=f"grid point {point}: input {ai}", errors=point_errors,
-            )
-            refs.append(_Ref(arr[sl]))
-        for oi, (out, spec) in enumerate(zip(outs, call.out_specs)):
-            idx = block_index(spec, point, prefetch)
-            sl = tile_slices(
-                idx, tuple(spec.block_shape), out.shape,
-                where=f"grid point {point}: output {oi}", errors=point_errors,
-            )
-            refs.append(_Ref(out[sl]))
+                where=f"grid point {point}: {what}", errors=point_errors,
+            )))
         if point_errors:
             call.errors.extend(point_errors)
             continue
+        refs = [_Ref(p) for p in prefetch] + [_Ref(a[sl]) for a, sl in views]
         with _patched_pl(tuple(point), call.grid):
-            call.kernel(*refs)
+            try:
+                call.kernel(*refs, *scratch)
+            except IndexError as e:
+                call.errors.append(f"bounds: grid point {point}: {e}")
     return outs

@@ -13,6 +13,12 @@ execution needed:
 * **coverage** — the output index map must tile the output exactly: the
   block shape divides the operand, every tile is visited (no holes), and
   no tile index falls outside the operand (flagged as **bounds**).
+* **scratch carry** (a **race**) — a VMEM scratch value written at one
+  grid point and read at a later one (``simulate`` records each such
+  carry) crosses the first axis on which the two points differ.  That
+  axis and every axis inside it must be sequential: each core of a chip
+  that splits ``"parallel"`` axes owns its own scratch, so a carry across
+  a parallel axis reads another core's (never written) buffer.
 
 Dynamic theorems (``verify_case``), decided by running the kernel body on
 every grid point via ``simulate`` and comparing the builder's final return
@@ -62,6 +68,11 @@ class Problem:
         return f"[{self.kind}] {self.where}: {self.message}"
 
 
+def _tile(block_shape) -> tuple:
+    """Block shape with squeezed (``None``) dims counted as unit tiles."""
+    return tuple(1 if b is None else b for b in block_shape)
+
+
 def check_call(call: KernelCall, where: str = "pallas_call") -> List[Problem]:
     """Static race/coverage theorems plus the simulator's bounds record."""
     problems: List[Problem] = []
@@ -97,7 +108,7 @@ def check_call(call: KernelCall, where: str = "pallas_call") -> List[Problem]:
 
     points = list(np.ndindex(*grid))
     for oi, (spec, out) in enumerate(zip(call.out_specs, call.out_shapes)):
-        bs = tuple(spec.block_shape)
+        bs = _tile(spec.block_shape)
         shape = tuple(out.shape)
         if len(bs) != len(shape):
             problems.append(
@@ -163,7 +174,27 @@ def check_call(call: KernelCall, where: str = "pallas_call") -> List[Problem]:
                         f"must be the innermost sequential dims",
                     )
                 )
+    problems.extend(_carry_problems(call, sem, where))
     return problems
+
+
+def _carry_problems(call: KernelCall, sem, where: str) -> List[Problem]:
+    """The scratch-carry theorem, one problem per (scratch, carry axis)."""
+    found = {}
+    for si, src, dst in sorted(call.carries):
+        a_pt = tuple(int(c) for c in np.unravel_index(src, call.grid))
+        b_pt = tuple(int(c) for c in np.unravel_index(dst, call.grid))
+        axis = next(d for d in range(len(a_pt)) if a_pt[d] != b_pt[d])
+        bad = [d for d in range(axis, len(sem)) if sem[d] == "parallel"]
+        if bad and (si, axis) not in found:
+            found[(si, axis)] = Problem(
+                "race", where,
+                f"scratch {si}: written at grid point {a_pt}, read at "
+                f"{b_pt} — carried along axis {axis}, but axes {bad} are "
+                f"declared 'parallel' (a carry axis and every axis inside "
+                f"it must be 'arbitrary': each core owns its scratch)",
+            )
+    return list(found.values())
 
 
 def _resolve_builder(case):

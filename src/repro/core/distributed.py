@@ -42,8 +42,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 from .semiring import INF, TROPICAL, Semiring, ceil_log2
 
 
@@ -80,6 +78,13 @@ def _bcast(value: jax.Array, axes, src, my_index) -> jax.Array:
     ``axes`` — masked psum (everyone else contributes zeros)."""
     contrib = jnp.where(my_index == src, value, jnp.zeros_like(value))
     return lax.psum(contrib, axes)
+
+
+def _shard_map(body, mesh: Mesh, in_specs, out_spec):
+    """``jax.shard_map`` without varying-axis checks: the per-shard bodies
+    call Pallas kernels, whose output shapes carry no varying-axis type."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_spec, check_vma=False)
 
 
 def _panel_coords(p, k_shard: int, panels_per_shard: int, panel: int):
@@ -145,17 +150,12 @@ def summa_minplus(
         if rest:
             acc0 = rest[0]                          # fused Z = min(acc, X(x)Y)
         else:
-            acc0 = compat.pvary(
-                jnp.full((m_l, n_l), sr.zero, x.dtype),
-                tuple(row_axes) + tuple(col_axes),
-            )
+            acc0 = jnp.full((m_l, n_l), sr.zero, x.dtype)
         return lax.fori_loop(0, npanels, step, acc0)
 
-    if acc is None:
-        fn = compat.shard_map(body, mesh=mesh, in_specs=(spec, spec), out_specs=spec)
-        return fn(x, y)
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    return fn(x, y, acc)
+    specs = (spec, spec) if acc is None else (spec, spec, spec)
+    fn = _shard_map(body, mesh, specs, spec)
+    return fn(x, y) if acc is None else fn(x, y, acc)
 
 
 @partial(jax.jit, static_argnames=("mesh", "row_axes", "col_axes", "iters", "semiring"))
@@ -244,8 +244,7 @@ def fw_distributed(
 
         return lax.fori_loop(0, nblk, pivot_step, dl)
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec)
-    return fn(h)
+    return _shard_map(body, mesh, (spec,), spec)(h)
 
 
 def rkleene_distributed(

@@ -151,10 +151,12 @@ def generate_edge_updates(
     edge (integer-valued, floor 1) or insert a new one with cost in
     [1, alpha) — i.e. the streaming load shape the dynamic engine's exact
     rank-k path covers.  ``worsen_frac`` > 0 additionally worsens that
-    fraction of the batch (cost + [100, 300)), exercising the bounded
-    re-solve path.  Shared by the dynamic differential tests, the
-    incremental benchmark, and the serve mutate stream so all three stay on
-    one load definition.  Never emits self-loops.
+    fraction of the batch: each such entry is redrawn onto an existing
+    out-edge of its source (a "worsened" non-edge would be an insert) and
+    gets cost + [100, 300), exercising the bounded re-solve path.  Shared
+    by the dynamic differential tests, the incremental benchmark, and the
+    serve mutate stream so all three stay on one load definition.  Never
+    emits self-loops.
     """
     n = h.shape[0]
     u = rng.integers(0, n, k).astype(np.int32)
@@ -167,6 +169,12 @@ def generate_edge_updates(
     ).astype(np.float32)
     if worsen_frac > 0.0:
         worsen = rng.uniform(size=k) < worsen_frac
+        for i in np.flatnonzero(worsen):
+            out = np.flatnonzero(np.isfinite(h[u[i]]))
+            out = out[out != u[i]]
+            if out.size:
+                v[i] = out[rng.integers(0, out.size)]
+        old = h[u, v]
         w = np.where(
             worsen,
             np.where(np.isfinite(old), old, 1.0)

@@ -37,7 +37,8 @@ Environment:
   * ``REPRO_AUTOTUNE=force``  :func:`tune` re-measures and overwrites even
                               when a cached winner exists.
   * ``REPRO_AUTOTUNE_CACHE``  cache file path (default
-                              ``~/.cache/repro/autotune.json``).
+                              ``<checkout>/.cache/autotune.json``, see
+                              ``repro.caches``).
 
 Note: solvers are jit-compiled and read the cache at trace time — tune
 before the first solver call of a given shape (the harnesses do), or new
@@ -110,7 +111,9 @@ def cache_path() -> Path:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE", "")
     if env:
         return Path(env)
-    return Path.home() / ".cache" / "repro" / "autotune.json"
+    from repro.caches import autotune_cache_file
+
+    return autotune_cache_file()
 
 
 def bucket(v: int) -> int:
@@ -321,22 +324,23 @@ def candidates(backend: str, m: int, k: int, n: int) -> List[dict]:
             if rc <= mb and kc < kb
         ]
         return out
-    # Pallas lattice: vreg-aligned blocks only; bk always a multiple of kc.
-    from .minplus import DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, DEFAULT_KC
+    # Pallas lattice: only tilings Mosaic compiles — (8, 128)-aligned
+    # blocks, and the in-tile k window kc = 128 (the kernel reads x at lane
+    # offsets it must prove 128-aligned; shorter contractions clamp kc to
+    # the whole block in ``minplus._layout``).
+    from .minplus import DEFAULT_KC
 
     out, seen = [], set()
     for bm in (64, 128, 256):
         for bn in (128, 256):
             for bk in (256, 512):
-                for kc in (8, 16):
-                    cand = (min(bm, bucket(m)), min(bn, max(bucket(n), 128)),
-                            min(bk, bucket(k)), kc)
-                    if cand[2] % kc or cand in seen:
-                        continue
+                bk_ = min(bk, bucket(k))
+                cand = (min(bm, bucket(m)), min(bn, max(bucket(n), 128)),
+                        bk_, min(DEFAULT_KC, bk_))
+                if cand not in seen:
                     seen.add(cand)
                     out.append(dict(zip(_PALLAS_KEYS, cand)))
-    return out or [dict(zip(_PALLAS_KEYS,
-                            (DEFAULT_BM, DEFAULT_BN, DEFAULT_BK, DEFAULT_KC)))]
+    return out
 
 
 def measure(fn, reps: int) -> float:
@@ -468,16 +472,17 @@ def _row_close_candidates(backend: str, r: int, n: int) -> List[dict]:
             if cand not in out:
                 out.append(cand)
         return out
+    from .minplus import DEFAULT_KC
+
     out, seen = [], set()
     for bn in (128, 256):
         for bk in (256, 512):
-            for kc in (8, 16):
-                cand = (min(bn, max(bucket(n), 128)), min(bk, bucket(n)), kc)
-                if cand[1] % kc or cand in seen:
-                    continue
+            bk_ = min(bk, bucket(n))
+            cand = (min(bn, max(bucket(n), 128)), bk_, min(DEFAULT_KC, bk_))
+            if cand not in seen:
                 seen.add(cand)
                 out.append(dict(zip(_ROWCLOSE_PALLAS_KEYS, cand)))
-    return out or [dict(zip(_ROWCLOSE_PALLAS_KEYS, (128, 512, 8)))]
+    return out
 
 
 def tune_row_close(

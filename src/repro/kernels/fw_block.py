@@ -22,6 +22,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.semiring import TROPICAL, Semiring
 
@@ -30,37 +31,98 @@ INF = jnp.inf
 __all__ = ["fw_block_pallas", "fw_block_pred_pallas", "PALLAS_BUILDERS"]
 
 
+def _window(b: int) -> int:
+    """Lane window the pivot loop walks: 128 when it tiles the edge, else
+    the whole (single-window) edge."""
+    return 128 if b % 128 == 0 else b
+
+
+def _close(d_ref, p_ref, *, sr: Semiring) -> None:
+    """Close the (B, B) tile held in ``d_ref`` in place (and its
+    predecessors in ``p_ref``): B dependent rank-1 pivot steps.
+
+    Row k is read at a dynamic sublane offset.  Column k is picked out of
+    the 128-lane window that holds it (Mosaic loads lanes only at provably
+    128-aligned offsets) by a ⊕-reduction over a one-hot lane mask — exact,
+    since every other lane contributes the semiring zero.  Both are re-read
+    every step: step k' < k may have improved them."""
+    b = d_ref.shape[-1]
+    lc = _window(b)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (b, lc), 1)
+
+    def step(k, carry):
+        k0 = pl.multiple_of((k // lc) * lc, lc) if lc < b else 0
+        win = d_ref[:, pl.ds(k0, lc)]                           # (B, lc)
+        col = sr.reduce(jnp.where(lane == k - k0, win, sr.zero),
+                        axis=1, keepdims=True)                  # (B, 1)
+        row = d_ref[pl.ds(k, 1), :]                             # (1, B)
+        cur = d_ref[...]
+        via = sr.mul(col, row)
+        if p_ref is None:
+            d_ref[...] = sr.add(cur, via)
+            return carry
+        better = sr.better(via, cur)
+        d_ref[...] = jnp.where(better, via, cur)
+        p_ref[...] = jnp.where(better, p_ref[pl.ds(k, 1), :], p_ref[...])
+        return carry
+
+    jax.lax.fori_loop(0, b, step, 0)
+
+
+def _closure_call(d, p, *, interpret: bool, semiring: Semiring):
+    """(T, B, B) tiles (and predecessors) -> closed tiles: one grid program
+    per independent tile."""
+    t, b, _ = d.shape
+    spec = pl.BlockSpec((None, b, b), lambda i: (i, 0, 0))
+    pred = p is not None
+
+    def kern(*refs):
+        d_ref, o_ref = refs[0], refs[1 + pred]
+        o_ref[...] = d_ref[...]
+        po_ref = None
+        if pred:
+            po_ref = refs[3]
+            po_ref[...] = refs[1][...]
+        _close(o_ref, po_ref, sr=semiring)
+
+    out_shape = jax.ShapeDtypeStruct((t, b, b), d.dtype)
+    params = {}
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        )
+    return pl.pallas_call(
+        kern,
+        grid=(t,),
+        in_specs=[spec, spec] if pred else [spec],
+        out_specs=(spec, spec) if pred else spec,
+        out_shape=(
+            (out_shape, jax.ShapeDtypeStruct((t, b, b), jnp.int32))
+            if pred else out_shape
+        ),
+        interpret=interpret,
+        **params,
+    )(*((d, p) if pred else (d,)))
+
+
+def close_tiles(
+    d: jax.Array, *, interpret: bool = False, semiring: Semiring = TROPICAL
+) -> jax.Array:
+    """Unjitted body of :func:`fw_block_pallas` (the fused round calls it
+    inside its own trace)."""
+    batched = d.ndim == 3
+    dd = d if batched else d[None]
+    assert dd.shape[1] == dd.shape[2], d.shape
+    out = _closure_call(dd, None, interpret=interpret, semiring=semiring)
+    return out if batched else out[0]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "semiring"))
 def fw_block_pallas(
     d: jax.Array, *, interpret: bool = False, semiring: Semiring = TROPICAL
 ) -> jax.Array:
     """Close one (B, B) tile, or a batch (T, B, B) of independent tiles."""
-    sr = semiring
-    batched = d.ndim == 3
-    dd = d if batched else d[None]
-    t, b, b2 = dd.shape
-    assert b == b2, d.shape
-    spec = pl.BlockSpec((1, b, b), lambda i: (i, 0, 0))
-
-    def kern(d_ref, o_ref):
-        d0 = d_ref[0]
-
-        def body(k, cur):
-            col = jax.lax.dynamic_slice(cur, (0, k), (b, 1))
-            row = jax.lax.dynamic_slice(cur, (k, 0), (1, b))
-            return sr.add(cur, sr.mul(col, row))
-
-        o_ref[0] = jax.lax.fori_loop(0, b, body, d0)
-
-    out = pl.pallas_call(
-        kern,
-        grid=(t,),
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((t, b, b), d.dtype),
-        interpret=interpret,
-    )(dd)
-    return out if batched else out[0]
+    return close_tiles(d, interpret=interpret, semiring=semiring)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "semiring"))
@@ -69,44 +131,11 @@ def fw_block_pred_pallas(
     semiring: Semiring = TROPICAL,
 ) -> Tuple[jax.Array, jax.Array]:
     """Closure with predecessor tracking (global node ids in ``p``)."""
-    sr = semiring
     batched = d.ndim == 3
     dd = d if batched else d[None]
     pp = p if batched else p[None]
-    t, b, b2 = dd.shape
-    assert b == b2 and pp.shape == dd.shape
-    spec = pl.BlockSpec((1, b, b), lambda i: (i, 0, 0))
-
-    def kern(d_ref, p_ref, do_ref, po_ref):
-        d0, p0 = d_ref[0], p_ref[0]
-
-        def body(k, dp):
-            cur, pcur = dp
-            col = jax.lax.dynamic_slice(cur, (0, k), (b, 1))
-            row = jax.lax.dynamic_slice(cur, (k, 0), (1, b))
-            via = sr.mul(col, row)
-            pk = jax.lax.dynamic_slice(pcur, (k, 0), (1, b))
-            better = sr.better(via, cur)
-            return (
-                jnp.where(better, via, cur),
-                jnp.where(better, jnp.broadcast_to(pk, pcur.shape), pcur),
-            )
-
-        do, po = jax.lax.fori_loop(0, b, body, (d0, p0))
-        do_ref[0] = do
-        po_ref[0] = po
-
-    do, po = pl.pallas_call(
-        kern,
-        grid=(t,),
-        in_specs=[spec, spec],
-        out_specs=(spec, spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((t, b, b), d.dtype),
-            jax.ShapeDtypeStruct((t, b, b), jnp.int32),
-        ),
-        interpret=interpret,
-    )(dd, pp)
+    assert dd.shape[1] == dd.shape[2] and pp.shape == dd.shape
+    do, po = _closure_call(dd, pp, interpret=interpret, semiring=semiring)
     return (do, po) if batched else (do[0], po[0])
 
 

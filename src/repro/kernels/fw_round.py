@@ -1,27 +1,30 @@
-"""Multi-stage fused blocked-FW k-round — one Pallas dispatch per round.
+"""Multi-stage fused blocked-FW k-round — one row-stripe grid per round.
 
 The legacy blocked-FW round is four kernel launches (pivot closure, row
 panel, col panel, phase-3 outer update) plus stripe copies; Lund & Smith's
-multi-stage CUDA kernel shows the whole round fits in one launch when each
-output tile redundantly closes the pivot block on-core.  This kernel is
-that scheme on the Pallas grid:
+multi-stage CUDA kernel shows the panel work fits in one launch.  This
+kernel is that scheme on the Pallas grid, tiled so every block fits VMEM at
+any n:
 
-  grid = (G, N/B) row stripes; program (g, i) owns the (B, N) output stripe
-  and receives, via scalar-prefetched pivot index t:
-    * its stripe of D (the ⊕-accumulate operand),
-    * the pivot row panel  D[o:o+B, :]   (same block for every i),
-    * its col-panel tile   D[i·B:(i+1)·B, o:o+B].
-
-  body:  A* = FW(pivot)                      (closure, on-core, f32)
-         col' = col ⊗ A*                     ((B,B) ⊗-product)
-         out  = stripe ⊕ col' ⊗ rowpanel     (fused accumulate)
+  stage 1: A* = FW(pivot)            own dispatch (``fw_block``), once per round
+  grid = (G, N/B, N/bn); program (g, i, j) owns output tile (B, bn) of
+  stripe i and receives, via the scalar-prefetched pivot index t:
+    * its tile of D (the ⊕-accumulate operand),
+    * the pivot row panel tile  D[o:o+B, j·bn:(j+1)·bn],
+    * its col-panel tile        D[i·B:(i+1)·B, o:o+B]  (pre-sliced panel),
+    * the closed pivot A*.
+  body:  col' = col ⊗ A*                  (once per stripe, at j = 0, kept in
+                                           a VMEM scratch across the j sweep)
+         out  = tile ⊕ col' ⊗ rowpanel    (fused accumulate)
 
 The stage-3 accumulate re-derives the row/col stripes and the pivot block
 by subsumption (see ``core.blocked_fw``), so the round writes each output
 element exactly once and no ``dynamic_update_slice`` pass exists.  The
-pivot closure and col' product are recomputed per stripe — O(N·B^2) extra
-⊗-work per round, the classic multi-stage trade for launch count and HBM
-round-trips.
+pivot is closed once per round, not once per program: the closure adds no
+redundant work (its B^3 ⊕⊗ steps per round are 2nB^2 in all, a B^2/n^2
+share of the 2n^3 total).  col' costs n·B^2 per round, the col-panel
+product itself.  The j axis is "arbitrary" because the col' scratch is
+carried across it; g and i are "parallel".
 
 Bit-exactness: the candidate sums are identical to the chunked-XLA
 fallback (``minplus_xla.fw_round_xla``) — same closure fold, same
@@ -47,16 +50,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.semiring import TROPICAL, Semiring
 
-from .minplus import _minplus_body
+from .fw_block import _window, close_tiles
+from .minplus import _fold
 
 __all__ = ["fw_round_pallas", "PALLAS_BUILDERS"]
 
 
-def _kc_for(b: int, kc: int = 8) -> int:
-    """Largest in-tile k chunk from the vreg-friendly ladder dividing B."""
-    while kc > 1 and b % kc:
-        kc //= 2
-    return max(kc, 1)
+def _col_tile(n: int) -> int:
+    """Output tile width: the widest 128-multiple dividing n, else all of n."""
+    for bn in (512, 256, 128):
+        if n % bn == 0:
+            return bn
+    return n
 
 
 @functools.partial(
@@ -74,7 +79,7 @@ def fw_round_pallas(
 
     ``o`` is the (traced) element offset of the pivot block; N must be a
     multiple of ``block_size`` (the solver pads).  Returns the full updated
-    matrix — a single ``pallas_call``.
+    matrix: the pivot closure dispatch plus one row-stripe grid.
     """
     sr = semiring
     b = block_size
@@ -82,52 +87,52 @@ def fw_round_pallas(
     dd = d if batched else d[None]
     g, n, n2 = dd.shape
     assert n == n2 and n % b == 0, (d.shape, b)
-    kc = _kc_for(b)
     storage = d.dtype
     cd = jnp.float32 if storage == jnp.bfloat16 else storage
+    bn = _col_tile(n)
+    lc = _window(b)
 
-    def kern(t_ref, acc_ref, rowp_ref, colt_ref, o_ref):
-        rowpan = rowp_ref[0]                           # (b, n) pivot rows
-        colpan = colt_ref[0]                           # (b, b) col-panel tile
-        oo = t_ref[0] * b                              # pivot element offset
-        pivot = jax.lax.dynamic_slice(rowpan, (0, oo), (b, b)).astype(cd)
+    pivot = jax.lax.dynamic_slice(dd, (0, o, o), (g, b, b))
+    pivot = close_tiles(
+        pivot.astype(cd), interpret=interpret, semiring=sr
+    ).astype(storage)
+    colpan = jax.lax.dynamic_slice(dd, (0, 0, o), (g, n, b))
 
-        def piv_step(k, cur):
-            via = sr.mul(
-                jax.lax.dynamic_slice(cur, (0, k), (b, 1)),
-                jax.lax.dynamic_slice(cur, (k, 0), (1, b)),
-            )
-            return sr.add(cur, via)
+    def kern(t_ref, piv_ref, col_ref, row_ref, acc_ref, o_ref, colp_ref):
+        @pl.when(pl.program_id(2) == 0)
+        def _col_panel():
+            colp_ref[...] = jnp.full(colp_ref.shape, sr.zero, cd)
+            _fold(col_ref, piv_ref, colp_ref, kc=lc, sr=sr, cd=cd)
+            if storage != cd:                  # round col' like the fallback
+                colp_ref[...] = colp_ref[...].astype(storage).astype(cd)
 
-        pivot = jax.lax.fori_loop(0, b, piv_step, pivot).astype(storage)
-        colp, _ = _minplus_body(
-            colpan.astype(cd), pivot.astype(cd), kc, 0,
-            jnp.full((b, b), sr.zero, cd), None, sr,
-        )
-        colp = colp.astype(storage)
-        out, _ = _minplus_body(
-            colp.astype(cd), rowpan.astype(cd), kc, 0,
-            acc_ref[0].astype(cd), None, sr,
-        )
-        o_ref[0] = out.astype(storage)
+        _fold(colp_ref, row_ref, o_ref, a_ref=acc_ref, kc=lc, sr=sr, cd=cd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(g, n // b),
+        grid=(g, n // b, n // bn),
         in_specs=[
-            pl.BlockSpec((1, b, n), lambda gi, i, t: (gi, i, 0)),
-            pl.BlockSpec((1, b, n), lambda gi, i, t: (gi, t[0], 0)),
-            pl.BlockSpec((1, b, b), lambda gi, i, t: (gi, i, t[0])),
+            pl.BlockSpec((None, b, b), lambda gi, i, j, t: (gi, 0, 0)),
+            pl.BlockSpec((None, b, b), lambda gi, i, j, t: (gi, i, 0)),
+            pl.BlockSpec((None, b, bn), lambda gi, i, j, t: (gi, t[0], j)),
+            pl.BlockSpec((None, b, bn), lambda gi, i, j, t: (gi, i, j)),
         ],
-        out_specs=pl.BlockSpec((1, b, n), lambda gi, i, t: (gi, i, 0)),
+        out_specs=pl.BlockSpec((None, b, bn), lambda gi, i, j, t: (gi, i, j)),
+        scratch_shapes=[pltpu.VMEM((b, b), cd)],
     )
+    params = {}
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        )
     t = jnp.reshape(o // b, (1,)).astype(jnp.int32)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, n, n), storage),
         interpret=interpret,
-    )(t, dd, dd, dd)
+        **params,
+    )(t, pivot, colpan, dd, dd)
     return out if batched else out[0]
 
 

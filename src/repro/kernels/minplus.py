@@ -4,15 +4,17 @@ The paper materializes ``L[i,k,j] = X[i,k] + Y[k,j]`` (N^3 bytes) and reduces
 with ``min``/``argmin``.  On TPU we never build L: the grid walks (M/bm,
 N/bn, K/bk) tiles with k innermost, each step streams an (bm, bk) X panel and
 a (bk, bn) Y panel through VMEM and folds a running elementwise ``min`` into
-the (bm, bn) output block.  The k-loop *inside* a tile is chunked (kc rows at
-a time) so the live broadcast is (bm, kc, bn) — a few hundred KB instead of
-the paper's n^3 wall.
+the (bm, bn) output block.  Inside a tile every k is a rank-1 update
+``acc = min(acc, x[:, k] + y[k, :])`` on a register-sized row band of the
+block, read straight from the VMEM refs — nothing larger than the
+accumulator is ever live, instead of the paper's n^3 wall.
 
 (min, +) has no multiply-accumulate, so this runs on the VPU (8x128 vector
 unit), not the 128x128 MXU; block shapes are multiples of the fp32 (8, 128)
-vreg tile.  The k grid dim is "arbitrary" (sequential) — the output block is
-revisited and accumulated across k steps, which TPU guarantees for the
-innermost grid dim.
+vreg tile, and the x panel is read in 128-lane windows (Mosaic accepts a
+dynamic lane offset only when it is provably a multiple of 128).  The k grid
+dim is "arbitrary" (sequential) — the output block is revisited and
+accumulated across k steps, which TPU guarantees for the innermost grid dim.
 
 Batched dispatch: (G, m, k) x (G, k, n) operands add a *leading* batch grid
 dimension — the whole multi-graph panel product is one ``pallas_call``
@@ -48,7 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.semiring import TROPICAL, Semiring
 
 INF = jnp.inf
@@ -64,101 +65,129 @@ __all__ = [
 ]
 
 # fp32 vregs are (8, 128); MXU alignment is irrelevant here (VPU op), but
-# 128-lane alignment matters.  bk=512 amortizes grid overhead; kc=8 keeps the
-# (bm, kc, bn) broadcast at 128*8*128*4 B = 512 KiB of VREG/VMEM traffic.
+# 128-lane alignment matters: Mosaic loads the x panel only at lane offsets
+# it can prove are multiples of 128, so the in-tile k window ``kc`` is 128
+# (or the whole block when the contraction is shorter).  bk=512 amortizes
+# grid overhead.
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
-DEFAULT_KC = 8
+DEFAULT_KC = 128
+
+# live accumulator budget per row band: 32 f32 vregs (half the register
+# file), halved again when the int32 witness plane rides along
+_BAND_ELEMS = 32 * 1024
 
 
-def _minplus_body(x, y, kc: int, k_base, acc, idx, sr: Semiring):
-    """Fold ⊕ over the k dim of x:(bm,bk), y:(bk,bn) into acc (and idx)."""
-    bm, bk = x.shape
-    bn = y.shape[1]
+def _minplus_body(x_ref, y_ref, acc, idx, *, r0, k_base, kc, sr, cd):
+    """Fold ⊕ over the k dim of ``x_ref[r0:r0+rows, :] ⊗ y_ref`` into acc.
+
+    ``x_ref`` is a (bm, bk) ref, ``y_ref`` a (bk, bn) ref, ``acc``/``idx``
+    (rows, bn) values.  The contraction is walked in windows of ``kc``
+    lanes (a multiple of 128 or the whole of bk); inside a window each k is
+    a static rank-1 update ``acc ⊕= x[:, k] ⊗ y[k, :]`` — a lane broadcast
+    of one x column against a sublane broadcast of one y row, so nothing
+    larger than the accumulator is ever live.  y rows are loaded in aligned
+    groups of one packed sublane tile.  With ``idx`` the update keeps a
+    strict-improvement witness (global k id): ties keep the earlier,
+    smaller k, exactly like the oracle's argmin.
+    """
+    rows = acc.shape[0]
     track = idx is not None
+    grp = _row_group(y_ref.dtype, kc)
 
-    def chunk(c, carry):
-        acc = carry[0] if track else carry
-        xs = jax.lax.dynamic_slice(x, (0, c * kc), (bm, kc))      # (bm, kc)
-        ys = jax.lax.dynamic_slice(y, (c * kc, 0), (kc, bn))      # (kc, bn)
-        l = sr.mul(xs[:, :, None], ys[None, :, :])                # (bm, kc, bn)
-        cand = sr.reduce(l, axis=1)
-        if not track:
-            return sr.add(acc, cand)
-        idx = carry[1]
-        ka = sr.argreduce(l, axis=1).astype(jnp.int32)            # local in chunk
-        kg = ka + (k_base + c * kc)                               # global k id
-        better = sr.better(cand, acc)
-        return jnp.where(better, cand, acc), jnp.where(better, kg, idx)
+    def window(k0, carry):
+        xs = x_ref[pl.ds(r0, rows), pl.ds(k0, kc)].astype(cd)     # (rows, kc)
+        for q in range(0, kc, grp):
+            ys = y_ref[pl.ds(k0 + q, grp), :].astype(cd)          # (grp, bn)
+            for j in range(q, q + grp):
+                cand = sr.mul(xs[:, j:j + 1], ys[j - q:j - q + 1, :])
+                if not track:
+                    carry = sr.add(carry, cand)
+                    continue
+                a, i = carry
+                better = sr.better(cand, a)
+                carry = (jnp.where(better, cand, a),
+                         jnp.where(better, k_base + k0 + j, i))
+        return carry
 
     init = (acc, idx) if track else acc
-    out = jax.lax.fori_loop(0, bk // kc, chunk, init)
+    out = _windows(x_ref.shape[-1], kc, window, init)
     return out if track else (out, None)
 
 
-def _ld(ref):
-    """Load a block, squeezing the leading singleton batch dim if present."""
-    v = ref[...]
-    return v[0] if v.ndim == 3 else v
-
-
-def _st(ref, val):
-    ref[...] = val[None] if len(ref.shape) == 3 else val
-
-
-def _kernel(x_ref, y_ref, z_ref, *, kc: int, bk: int, k_axis: int, sr: Semiring):
-    @pl.when(pl.program_id(k_axis) == 0)
-    def _init():
-        z_ref[...] = jnp.full_like(z_ref[...], sr.zero)
-
-    k_base = pl.program_id(k_axis) * bk
-    acc, _ = _minplus_body(_ld(x_ref), _ld(y_ref), kc, k_base, _ld(z_ref), None, sr)
-    _st(z_ref, acc)
-
-
-def _kernel_acc(
-    a_ref, x_ref, y_ref, z_ref, *, kc: int, bk: int, k_axis: int, sr: Semiring
-):
-    @pl.when(pl.program_id(k_axis) == 0)
-    def _init():
-        z_ref[...] = a_ref[...]
-
-    k_base = pl.program_id(k_axis) * bk
-    acc, _ = _minplus_body(_ld(x_ref), _ld(y_ref), kc, k_base, _ld(z_ref), None, sr)
-    _st(z_ref, acc)
-
-
-def _kernel_argmin(
-    x_ref, y_ref, z_ref, i_ref, *, kc: int, bk: int, k_axis: int, sr: Semiring
-):
-    @pl.when(pl.program_id(k_axis) == 0)
-    def _init():
-        z_ref[...] = jnp.full_like(z_ref[...], sr.zero)
-        i_ref[...] = jnp.full_like(i_ref[...], -1)
-
-    k_base = pl.program_id(k_axis) * bk
-    acc, idx = _minplus_body(
-        _ld(x_ref), _ld(y_ref), kc, k_base, _ld(z_ref), _ld(i_ref), sr
+def _windows(k: int, kc: int, body, init):
+    """Run ``body(k0, carry)`` over the kc-wide windows of a length-k axis:
+    a static offset when there is one window (Mosaic cannot prove a traced
+    offset lane-aligned), else a loop over 128-multiple offsets."""
+    if k == kc:
+        return body(0, init)
+    return jax.lax.fori_loop(
+        0, k // kc, lambda w, c: body(pl.multiple_of(w * kc, kc), c), init
     )
-    _st(z_ref, acc)
-    _st(i_ref, idx)
 
 
-def _kernel_acc_argmin(
-    a_ref, x_ref, y_ref, z_ref, i_ref, *, kc: int, bk: int, k_axis: int, sr: Semiring
-):
-    @pl.when(pl.program_id(k_axis) == 0)
+def _row_group(dtype, kc: int) -> int:
+    """Rows of y loaded at once: one sublane tile of the dtype (8 for 32-bit,
+    16 for bf16), so each dynamic load starts on a tile boundary."""
+    grp = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return grp if kc % grp == 0 else 1  # repro: allow-trace-impurity  kc is a static block size
+
+
+def _band_rows(bm: int, bn: int, track: bool) -> int:
+    """Rows per accumulator band: the whole block when it fits the budget,
+    else halved while it stays a multiple of 16 (one bf16 sublane tile)."""
+    budget = _BAND_ELEMS // (2 if track else 1)
+    rows = bm
+    while rows * bn > budget and rows % 32 == 0:
+        rows //= 2
+    return rows
+
+
+def _fold(x_ref, y_ref, z_ref, i_ref=None, *, a_ref=None, k_base=0, kc: int,
+          sr: Semiring, cd=None):
+    """``z ⊕= x ⊗ y`` over one grid step, one register-sized row band at a
+    time (``a_ref`` supplies the ⊕-operand when it is not ``z`` itself).
+    Values are computed in ``cd`` (default: z's dtype) and stored in z's."""
+    bm, bn = z_ref.shape
+    cd = z_ref.dtype if cd is None else cd
+    src = z_ref if a_ref is None else a_ref
+    track = i_ref is not None
+    rows = _band_rows(bm, bn, track)
+
+    def band(b, carry):
+        r0 = pl.multiple_of(b * rows, rows)
+        acc = src[pl.ds(r0, rows), :].astype(cd)
+        idx = i_ref[pl.ds(r0, rows), :] if track else None
+        acc, idx = _minplus_body(
+            x_ref, y_ref, acc, idx, r0=r0, k_base=k_base, kc=kc, sr=sr, cd=cd
+        )
+        z_ref[pl.ds(r0, rows), :] = acc.astype(z_ref.dtype)
+        if track:
+            i_ref[pl.ds(r0, rows), :] = idx
+        return carry
+
+    jax.lax.fori_loop(0, bm // rows, band, 0)
+
+
+def _kernel(*refs, kc: int, bk: int, k_axis: int, sr: Semiring,
+            accumulate: bool, track: bool):
+    """One grid step of Z (⊕)= X ⊗ Y; refs are ([a], x, y, z, [i])."""
+    a_ref = refs[0] if accumulate else None
+    x_ref, y_ref, z_ref = refs[accumulate:accumulate + 3]
+    i_ref = refs[accumulate + 3] if track else None
+    kk = pl.program_id(k_axis)
+
+    @pl.when(kk == 0)
     def _init():
-        z_ref[...] = a_ref[...]
-        i_ref[...] = jnp.full_like(i_ref[...], -1)
+        if accumulate:
+            z_ref[...] = a_ref[...]
+        else:
+            z_ref[...] = jnp.full(z_ref.shape, sr.zero, z_ref.dtype)
+        if track:
+            i_ref[...] = jnp.full(i_ref.shape, -1, jnp.int32)
 
-    k_base = pl.program_id(k_axis) * bk
-    acc, idx = _minplus_body(
-        _ld(x_ref), _ld(y_ref), kc, k_base, _ld(z_ref), _ld(i_ref), sr
-    )
-    _st(z_ref, acc)
-    _st(i_ref, idx)
+    _fold(x_ref, y_ref, z_ref, i_ref, k_base=kk * bk, kc=kc, sr=sr)
 
 
 def _pad(arr, m0, m1, value):
@@ -174,9 +203,9 @@ def _pad(arr, m0, m1, value):
 def _specs(batched: bool, bm: int, bn: int, bk: int):
     if batched:
         return (
-            pl.BlockSpec((1, bm, bk), lambda g, i, j, kk: (g, i, kk)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, kk: (g, kk, j)),
-            pl.BlockSpec((1, bm, bn), lambda g, i, j, kk: (g, i, j)),
+            pl.BlockSpec((None, bm, bk), lambda g, i, j, kk: (g, i, kk)),
+            pl.BlockSpec((None, bk, bn), lambda g, i, j, kk: (g, kk, j)),
+            pl.BlockSpec((None, bm, bn), lambda g, i, j, kk: (g, i, j)),
         )
     return (
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -190,7 +219,7 @@ def _grid_call(kernel, grid, in_specs, out_specs, out_shape, interpret):
     if not interpret:
         # batch/m/n blocks are independent; k must stay sequential
         # (accumulation) and is always the innermost grid dim.
-        params["compiler_params"] = tpu_compiler_params(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",)
         )
     return pl.pallas_call(
@@ -214,6 +243,7 @@ def _layout(x, y, bm, bn, bk, kc, fill=INF):
     k2, n = y.shape[-2], y.shape[-1]
     assert k == k2, (x.shape, y.shape)
     bm, bn = min(bm, _rup(m, 8)), min(bn, _rup(n, 128))
+    kc = min(kc, _rup(k, 8))
     bk = min(_rup(bk, kc), _rup(k, kc))
     xp = _pad(x, bm, bk, fill)
     yp = _pad(y, bk, bn, fill)
@@ -225,7 +255,7 @@ def _layout(x, y, bm, bn, bk, kc, fill=INF):
         grid = (x.shape[0],) + grid
         out_dims = (x.shape[0],) + out_dims
     x_spec, y_spec, z_spec = _specs(batched, bm, bn, bk)
-    return batched, m, n, xp, yp, grid, x_spec, y_spec, z_spec, out_dims
+    return batched, m, n, xp, yp, grid, x_spec, y_spec, z_spec, out_dims, kc
 
 
 @functools.partial(
@@ -252,29 +282,9 @@ def minplus_pallas(
     back.  (G, ., .) operands run the whole batch on one kernel grid
     (leading batch dimension).
     """
-    sr = semiring
-    batched, m, n, xp, yp, grid, x_spec, y_spec, z_spec, out_dims = _layout(
-        x, y, bm, bn, bk, kc, sr.zero
-    )
-    bk_eff = xp.shape[-1] // grid[-1]
-    k_axis = len(grid) - 1
-    out_shape = jax.ShapeDtypeStruct(out_dims, x.dtype)
-
-    if accumulate:
-        assert a is not None and a.shape[-2:] == (m, n)
-        ap = _pad(a, z_spec.block_shape[-2], z_spec.block_shape[-1], sr.zero)
-        fn = _grid_call(
-            functools.partial(_kernel_acc, kc=kc, bk=bk_eff, k_axis=k_axis, sr=sr),
-            grid, [z_spec, x_spec, y_spec], z_spec, out_shape, interpret,
-        )
-        zp = fn(ap, xp, yp)
-    else:
-        fn = _grid_call(
-            functools.partial(_kernel, kc=kc, bk=bk_eff, k_axis=k_axis, sr=sr),
-            grid, [x_spec, y_spec], z_spec, out_shape, interpret,
-        )
-        zp = fn(xp, yp)
-    return zp[..., :m, :n]
+    z, _ = _call(x, y, a, bm=bm, bn=bn, bk=bk, kc=kc, accumulate=accumulate,
+                 track=False, interpret=interpret, sr=semiring)
+    return z
 
 
 @functools.partial(
@@ -305,36 +315,33 @@ def minplus_argmin_pallas(
     where ``a`` was kept).  Batched (G, ., .) operands run on one kernel
     grid.
     """
-    sr = semiring
-    batched, m, n, xp, yp, grid, x_spec, y_spec, z_spec, out_dims = _layout(
+    return _call(x, y, a, bm=bm, bn=bn, bk=bk, kc=kc, accumulate=accumulate,
+                 track=True, interpret=interpret, sr=semiring)
+
+
+def _call(x, y, a, *, bm, bn, bk, kc, accumulate, track, interpret, sr):
+    """Shared body of both wrappers: pad, grid, one ``pallas_call``, slice."""
+    batched, m, n, xp, yp, grid, x_spec, y_spec, z_spec, out_dims, kc = _layout(
         x, y, bm, bn, bk, kc, sr.zero
     )
-    bk_eff = xp.shape[-1] // grid[-1]
-    k_axis = len(grid) - 1
-    out_shape = (
-        jax.ShapeDtypeStruct(out_dims, x.dtype),
-        jax.ShapeDtypeStruct(out_dims, jnp.int32),
+    kern = functools.partial(
+        _kernel, kc=kc, bk=xp.shape[-1] // grid[-1], k_axis=len(grid) - 1,
+        sr=sr, accumulate=accumulate, track=track,
     )
-
+    in_specs, operands = [x_spec, y_spec], [xp, yp]
     if accumulate:
         assert a is not None and a.shape[-2:] == (m, n)
         ap = _pad(a, z_spec.block_shape[-2], z_spec.block_shape[-1], sr.zero)
-        fn = _grid_call(
-            functools.partial(
-                _kernel_acc_argmin, kc=kc, bk=bk_eff, k_axis=k_axis, sr=sr
-            ),
-            grid, [z_spec, x_spec, y_spec], (z_spec, z_spec), out_shape, interpret,
-        )
-        zp, ip = fn(ap, xp, yp)
-    else:
-        fn = _grid_call(
-            functools.partial(
-                _kernel_argmin, kc=kc, bk=bk_eff, k_axis=k_axis, sr=sr
-            ),
-            grid, [x_spec, y_spec], (z_spec, z_spec), out_shape, interpret,
-        )
-        zp, ip = fn(xp, yp)
-    return zp[..., :m, :n], ip[..., :m, :n]
+        in_specs, operands = [z_spec] + in_specs, [ap] + operands
+    z_shape = jax.ShapeDtypeStruct(out_dims, x.dtype)
+    if track:
+        i_shape = jax.ShapeDtypeStruct(out_dims, jnp.int32)
+        fn = _grid_call(kern, grid, in_specs, (z_spec, z_spec),
+                        (z_shape, i_shape), interpret)
+        zp, ip = fn(*operands)
+        return zp[..., :m, :n], ip[..., :m, :n]
+    zp = _grid_call(kern, grid, in_specs, z_spec, z_shape, interpret)(*operands)
+    return zp[..., :m, :n], None
 
 
 def _rup(v: int, m: int) -> int:

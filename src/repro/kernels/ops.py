@@ -451,7 +451,7 @@ def fw_round(
 
     ``o`` is the (traced) element offset of pivot block t = o // B.  The
     three stages (pivot closure, col' = col ⊗ A*, fused full accumulate
-    D ⊕ col' ⊗ row) run as a single Pallas grid dispatch on the
+    D ⊕ col' ⊗ row) run as a closure dispatch plus one Pallas grid on the
     pallas/interpret backends (``kernels.fw_round``) and as one jitted
     chunked-XLA program on the fallback (``minplus_xla.fw_round_xla``) —
     replacing the legacy 4-product round.  Accepts (N, N) or batched

@@ -20,7 +20,10 @@ maps can gather row ``rows[i]`` of D for the X panel and ⊕-operand while
 streaming the full matrix as Y — no host-side ``d[rows]`` materialization
 and no second dispatch for the write-back panel.  The row block size is
 pinned to 1 (a gather has no contiguous row tile), so only (bn, bk, kc)
-are tunable — the ``rowclose|…`` autotune family.
+are tunable — the ``rowclose|…`` autotune family.  The gathered operands
+are viewed as (n, 1, cols): a (1, 1, cols) block whose second-minor dim
+is the whole (unit) axis satisfies the TPU's (8, 128) block rule, which a
+(1, cols) block of an (n, cols) matrix does not.
 
 Because the X panel, ⊕-operand, and Y matrix need different padded
 column counts (bk vs bn multiples) and a gathered row dim cannot be
@@ -44,36 +47,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.semiring import TROPICAL, Semiring
 
-from .minplus import DEFAULT_BK, DEFAULT_BN, DEFAULT_KC, _minplus_body, _pad, _rup
+from .minplus import DEFAULT_BK, DEFAULT_BN, DEFAULT_KC, _fold, _pad, _rup
 
 __all__ = ["row_close_pallas", "PALLAS_BUILDERS"]
 
 
-def _kernel(rows_ref, x_ref, y_ref, a_ref, z_ref, *, kc, bk, sr):
+def _kernel(rows_ref, x_ref, y_ref, a_ref, z_ref, *i_ref, kc, bk, sr):
+    i_ref = i_ref[0] if i_ref else None
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
         z_ref[...] = a_ref[...]
+        if i_ref is not None:
+            i_ref[...] = jnp.full(i_ref.shape, -1, jnp.int32)
 
-    k_base = pl.program_id(2) * bk
-    acc, _ = _minplus_body(x_ref[...], y_ref[...], kc, k_base, z_ref[...], None, sr)
-    z_ref[...] = acc
-
-
-def _kernel_argmin(rows_ref, x_ref, y_ref, a_ref, z_ref, i_ref, *, kc, bk, sr):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        z_ref[...] = a_ref[...]
-        i_ref[...] = jnp.full_like(i_ref[...], -1)
-
-    k_base = pl.program_id(2) * bk
-    acc, idx = _minplus_body(
-        x_ref[...], y_ref[...], kc, k_base, z_ref[...], i_ref[...], sr
-    )
-    z_ref[...] = acc
-    i_ref[...] = idx
+    _fold(x_ref, y_ref, z_ref, i_ref, k_base=pl.program_id(2) * bk, kc=kc,
+          sr=sr)
 
 
 @functools.partial(
@@ -104,59 +95,48 @@ def row_close_pallas(
     assert d.ndim == 2 and d.shape[0] == n, d.shape
     r = rows.shape[0]
     bn_ = min(bn, _rup(n, 128))
+    kc = min(kc, _rup(n, 8))
     bk_ = min(_rup(bk, kc), _rup(n, kc))
-    dx = _pad(d, 1, bk_, sr.zero)        # (n, kp)  X gather source
-    dy = _pad(d, bk_, bn_, sr.zero)      # (kp, np) streamed Y
-    da = _pad(d, 1, bn_, sr.zero)        # (n, np)  ⊕-operand gather source
+    dy = _pad(d, bk_, bn_, sr.zero)                       # (kp, np) streamed Y
     kp, np_ = dy.shape
+    dx = _pad(d, 1, bk_, sr.zero).reshape(n, 1, kp)       # X gather source
+    da = _pad(d, 1, bn_, sr.zero).reshape(n, 1, np_)      # ⊕-operand source
     grid = (r, np_ // bn_, kp // bk_)
+    out_spec = pl.BlockSpec((None, 1, bn_), lambda i, j, kk, rows: (i, 0, j))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk_), lambda i, j, kk, rows: (rows[i], kk)),
+            pl.BlockSpec((None, 1, bk_), lambda i, j, kk, rows: (rows[i], 0, kk)),
             pl.BlockSpec((bk_, bn_), lambda i, j, kk, rows: (kk, j)),
-            pl.BlockSpec((1, bn_), lambda i, j, kk, rows: (rows[i], j)),
+            pl.BlockSpec((None, 1, bn_), lambda i, j, kk, rows: (rows[i], 0, j)),
         ],
-        out_specs=(
-            pl.BlockSpec((1, bn_), lambda i, j, kk, rows: (i, j)),
-            pl.BlockSpec((1, bn_), lambda i, j, kk, rows: (i, j)),
-        )
-        if track
-        else pl.BlockSpec((1, bn_), lambda i, j, kk, rows: (i, j)),
+        out_specs=(out_spec, out_spec) if track else out_spec,
     )
     params = {}
     if not interpret:
         # row/col blocks are independent; k is a revisit-accumulate dim and
         # must stay sequential-innermost (same contract as minplus).
-        params["compiler_params"] = tpu_compiler_params(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
-    if track:
-        out_shape = (
-            jax.ShapeDtypeStruct((r, np_), d.dtype),
-            jax.ShapeDtypeStruct((r, np_), jnp.int32),
-        )
-        kern = functools.partial(_kernel_argmin, kc=kc, bk=bk_, sr=sr)
-        zp, ip = pl.pallas_call(
-            kern,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-            **params,
-        )(rows.astype(jnp.int32), dx, dy, da)
-        return zp[:, :n], ip[:, :n]
-    out_shape = jax.ShapeDtypeStruct((r, np_), d.dtype)
-    kern = functools.partial(_kernel, kc=kc, bk=bk_, sr=sr)
-    zp = pl.pallas_call(
-        kern,
+    z_shape = jax.ShapeDtypeStruct((r, 1, np_), d.dtype)
+    out_shape = (
+        (z_shape, jax.ShapeDtypeStruct((r, 1, np_), jnp.int32))
+        if track else z_shape
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, kc=kc, bk=bk_, sr=sr),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
         **params,
     )(rows.astype(jnp.int32), dx, dy, da)
-    return zp[:, :n], None
+    if track:
+        zp, ip = out
+        return zp[:, 0, :n], ip[:, 0, :n]
+    return out[:, 0, :n], None
 
 
 # Raw (unjitted) builder for the kernel grid verifier — see

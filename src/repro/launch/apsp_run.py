@@ -4,12 +4,11 @@ Generates a random cost matrix with the paper's generator, places it on the
 mesh as a 2D block grid, solves with the selected distributed method, and
 verifies against the single-device oracle for sizes where that is feasible.
 
-On this CPU host run it with a small fake mesh:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      python -m repro.launch.apsp_run --n 96 --method fw --mesh 4x2 --verify
-
-On a pod, --mesh 16x16 (or 2x16x16 with --multi-pod) uses the production
-meshes from launch/mesh.py.
+The mesh is built from ``jax.devices()``: on a four-chip TPU v5e host the
+default ``--mesh 2x2`` uses all four chips.  On a CPU, ask XLA for host
+devices first (the flag must be set before JAX starts):
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+      python -m repro.launch.apsp_run --n 96 --method fw --block-size 16 --verify
 """
 
 from __future__ import annotations
@@ -25,7 +24,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=96)
     ap.add_argument("--method", default="fw", choices=["squaring", "fw", "rkleene"])
-    ap.add_argument("--mesh", default="4x2", help="e.g. 4x2, 16x16, 2x16x16")
+    ap.add_argument("--mesh", default="2x2", help="e.g. 2x2, 4x1, 2x2x1")
     ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--rho", type=float, default=50.0)
     ap.add_argument("--verify", action="store_true")
@@ -35,17 +34,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     dims = tuple(int(x) for x in args.mesh.split("x"))
-    import os
-
-    need = int(np.prod(dims))
-    if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={need} "
-            + os.environ.get("XLA_FLAGS", "")
-        )
     import jax
 
+    from repro.caches import enable_compile_cache
     from repro.core.distributed import apsp_distributed
+
+    enable_compile_cache()
     from repro.core.graphgen import generate_np
 
     multi_pod = len(dims) == 3

@@ -28,7 +28,6 @@ import traceback
 
 import jax
 
-from repro import compat
 from repro.configs import ARCH_IDS, get_arch
 from repro.launch.builders import build_cell
 from repro.launch.mesh import make_production_mesh
@@ -66,7 +65,7 @@ def run_cell(arch_id: str, shape_id: str, multi_pod: bool, *, save: bool = True,
     n_chips = mesh.size
     t0 = time.time()
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             dr = build_cell(arch, cell, mesh)
             jitted = jax.jit(
                 dr.fn,

@@ -23,8 +23,11 @@ benchmarks): it waits until the queue is empty *and* no worker holds a
 drain.
 
 Worker failures cannot take the loop down: ``pool.drain`` already routes
-engine faults (requeue + quarantine + recovery), so an exception escaping
-it is a bug — it is recorded (count + traceback) and the worker moves on.
+transient engine faults (requeue + quarantine + recovery), so an exception
+escaping it is a persistent one (a kernel that fails to lower or compile)
+or a bug.  It is recorded (count + traceback), the slot is not requeued
+(its batches stay queued and counted by staleness), and the next
+:meth:`UpdateExecutor.flush` re-raises it on the caller's thread.
 """
 
 from __future__ import annotations
@@ -59,6 +62,7 @@ class UpdateExecutor:
         self._inflight: set = set()
         self._stopped = False
         self.last_error: Optional[str] = None
+        self._error: Optional[BaseException] = None     # raised by the next flush
         self.stats = Counters({
             "enqueued": 0, "drains": 0, "requeues": 0, "drain_errors": 0,
         })
@@ -91,7 +95,8 @@ class UpdateExecutor:
     def flush(self, timeout: Optional[float] = None) -> bool:
         """Block until the queue is empty and no drain is in flight;
         returns False on timeout (the chaos smoke treats that as a
-        deadlock and fails fast)."""
+        deadlock and fails fast).  Re-raises, once, an exception that
+        escaped a worker's drain since the last flush."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._cond:
             while self._queue or self._inflight:
@@ -101,6 +106,9 @@ class UpdateExecutor:
                     if remaining <= 0:
                         return False
                 self._cond.wait(remaining)
+            error, self._error = self._error, None
+        if error is not None:
+            raise error
         return True
 
     def backlog(self) -> int:
@@ -131,20 +139,26 @@ class UpdateExecutor:
                 gid = self._queue.popleft()
                 self._queued.discard(gid)
                 self._inflight.add(gid)
+            failed = False
             try:
                 self._pool.drain(gid)
                 self.stats.inc("drains")
-            except Exception:
-                # pool.drain routes every expected fault itself (requeue +
-                # quarantine + recovery); an escape is a bug — record it
-                # for the summary and keep the worker alive
+            except Exception as e:
+                # pool.drain routes every transient fault itself (requeue +
+                # quarantine + recovery); an escape recurs on a retry —
+                # record it, hand it to the next flush, and keep the worker
+                # alive without spinning on the slot
+                failed = True
                 self.stats.inc("drain_errors")
                 self.last_error = traceback.format_exc()
+                with self._cond:
+                    self._error = e
             finally:
                 with self._cond:
                     self._inflight.discard(gid)
                     self._cond.notify_all()
-            self._maybe_requeue(gid)
+            if not failed:
+                self._maybe_requeue(gid)
 
     def _maybe_requeue(self, gid: int) -> None:
         # batches that arrived while the drain ran (or that a crash-restore

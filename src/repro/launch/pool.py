@@ -30,9 +30,11 @@ Protection layers, outermost first:
   answering from its last-known-good snapshot while the supervisor
   re-solves.
 * **Bounded retry** — transient apply failures (``InjectedCrash`` under
-  chaos, any ``RuntimeError`` from the runtime) retry with exponential
+  chaos, a ``RuntimeError`` from the runtime) retry with exponential
   backoff + seeded jitter up to ``max_retries``, then quarantine + full
-  rebuild.
+  rebuild.  A lowering or compile failure (see :func:`transient`) fails
+  the same way on every attempt, so it propagates instead, with the
+  unapplied batches requeued so staleness stays exact.
 * **Snapshots** — every healthy commit double-buffers a host-side
   last-known-good ``(dist, pred)`` copy (donation-aware: the engine's
   donating updates consume *device* buffers, never these host arrays;
@@ -99,6 +101,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+from jax._src import compiler as _xla_compiler
 
 from repro.core import (
     DynamicAPSP,
@@ -113,10 +116,50 @@ from repro.core.semiring import SemiringLike
 from repro.checkpoint import load_engine_checkpoint, save_engine_checkpoint
 
 from .executor import UpdateExecutor
-from .faults import FaultInjector, InjectedCrash
+from .faults import FaultInjector
 from .stats import Counters
 
-__all__ = ["SlotState", "EngineSlot", "EnginePool", "QueryResult"]
+__all__ = [
+    "SlotState", "EngineSlot", "EnginePool", "QueryResult", "transient",
+    "watch_compile_failures",
+]
+
+
+_COMPILE_FAILURE = "_repro_compile_failure"
+_compile_watch = threading.Lock()
+_compile_watched = False
+
+
+def _mark_compile_failure(e: Exception) -> None:
+    # an XLA runtime error handler: tags the error raised by a failed
+    # compile and lets it propagate unchanged
+    setattr(e, _COMPILE_FAILURE, True)
+    return None
+
+
+def watch_compile_failures() -> None:
+    """Tag every error XLA raises while compiling, so :func:`transient` can
+    tell it from an execution-time fault of the same type.  Idempotent."""
+    global _compile_watched
+    with _compile_watch:
+        if not _compile_watched:
+            _xla_compiler.register_xla_runtime_error_handler(_mark_compile_failure)
+            _compile_watched = True
+
+
+def transient(e: BaseException) -> bool:
+    """True for an apply failure worth retrying: an injected crash or a
+    runtime fault such as a deleted donated buffer or an exhausted device
+    allocator.  A kernel the backend cannot lower (``NotImplementedError``)
+    or a program XLA refuses to compile (tagged once
+    :func:`watch_compile_failures` has run) recurs on every retry; hiding
+    it behind quarantine and snapshot answers would hide a broken device
+    path."""
+    return (
+        isinstance(e, RuntimeError)
+        and not isinstance(e, NotImplementedError)
+        and not getattr(e, _COMPILE_FAILURE, False)
+    )
 
 
 class SlotState:
@@ -185,6 +228,7 @@ class EngineSlot:
         events: Optional[List[Dict]] = None,
         durability_dir: Optional[str] = None,
     ):
+        watch_compile_failures()
         self.gid = gid
         self._h = np.array(h, np.float32)        # lint: allow-copy (host-side, authoritative)
         self._method = method
@@ -473,6 +517,8 @@ class EngineSlot:
                 self.injector.maybe_crash()
                 return self.engine.update(u, v, w)
             except RuntimeError as e:
+                if not transient(e):
+                    raise
                 # transient fault (InjectedCrash under chaos, runtime errors
                 # like a deleted donated buffer otherwise): bounded retry
                 # with exponential backoff + jitter, then quarantine + full
@@ -735,11 +781,15 @@ class EnginePool:
                 # fall through to per-batch application: drop only the
                 # poisoned batch(es), keep the rest
                 self.stats.inc("drain_fallbacks")
-            except RuntimeError as e:
-                # persistent apply fault (slot now quarantined): requeue and
-                # serve snapshots until the fault clears
-                self.stats.inc("updates_failed")
+            except Exception as e:
+                # requeue: a persistent apply fault (slot now quarantined)
+                # serves snapshots until the fault clears; anything else
+                # (a lowering or compile failure) propagates with its
+                # batches still counted by staleness
                 slot.pending = batches + slot.pending
+                if not transient(e):
+                    raise
+                self.stats.inc("updates_failed")
                 return [{"path": "failed", "error": str(e),
                          "slot_state": slot.state}]
         infos = []
@@ -750,9 +800,11 @@ class EnginePool:
                 self.stats.inc("updates_rejected")
                 infos.append({"path": "rejected", "error": str(e),
                               "slot_state": slot.state})
-            except RuntimeError as e:
-                self.stats.inc("updates_failed")
+            except Exception as e:
                 slot.pending = batches[i:] + slot.pending
+                if not transient(e):
+                    raise
+                self.stats.inc("updates_failed")
                 infos.append({"path": "failed", "error": str(e),
                               "slot_state": slot.state})
                 break
@@ -831,9 +883,17 @@ class EnginePool:
                     np.concatenate([b[2] for b in bs]),
                 ))
             try:
-                infos, deferred = apply_updates_batched(
-                    [slot.engine for slot, _ in popped], coalesced
-                )
+                try:
+                    infos, deferred = apply_updates_batched(
+                        [slot.engine for slot, _ in popped], coalesced
+                    )
+                except Exception:
+                    # e.g. a stacked program that fails to compile: requeue
+                    # every popped batch (re-applying is idempotent) and
+                    # surface the error
+                    for slot, bs in popped:
+                        slot.pending = bs + slot.pending
+                    raise
                 self.stats.inc("drain_batched")
                 deferred_set = set(deferred)
                 for i, (slot, bs) in enumerate(popped):
@@ -1089,16 +1149,22 @@ class EnginePool:
         return out
 
     def summary(self) -> Dict:
-        """Aggregate report: pool stats + per-slot stats + lifecycle +
-        injected-fault counts + recovery times."""
+        """Aggregate report: pool stats + per-slot stats + engine update
+        paths (live engines) + lifecycle + injected-fault counts + recovery
+        times."""
         slot_stats: Dict[str, int] = {}
+        engine_stats: Dict[str, int] = {}
         for slot in self.slots.values():
             for k, v in slot.stats.items():
                 slot_stats[k] = slot_stats.get(k, 0) + v
+            if slot.engine is not None:
+                for k, v in slot.engine.stats.items():
+                    engine_stats[k] = engine_stats.get(k, 0) + v
         rec = self.recovery_times()
         out = {
             "pool": dict(self.stats),
             "slots": slot_stats,
+            "engines": engine_stats,
             "states": self.state_counts(),
             "faults_injected": dict(self.injector.counts),
             "transitions": len([e for e in self.events if "from" in e]),

@@ -36,6 +36,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -156,6 +157,7 @@ def serve_apsp(
     with_pred: bool = False,
     semiring: str = "tropical",
     seed: int = 0,
+    report: Optional[dict] = None,
 ) -> int:
     """Continuous-batched APSP serving over a synthetic graph-request stream.
 
@@ -165,7 +167,9 @@ def serve_apsp(
     later cycle reuses it — that amortization is the whole point of the
     batched engine.  ``semiring`` serves any registry instance (widest
     path, reliability, reachability) from the same loop — the request
-    stream is recast into that semiring's domain.
+    stream is recast into that semiring's domain.  ``report``, when given,
+    receives the last batch's cost matrices (``"graphs"``) and its
+    :class:`repro.core.BatchAPSPResult` (``"result"``) for checking.
     """
     from repro.core import solve_batch
     from repro.core.graphgen import generate_np
@@ -229,12 +233,13 @@ def serve_apsp(
     from repro.core import get_semiring
 
     sr = get_semiring(semiring)
+    mats = res = None
     while done < n_requests:
         sizes = rng.integers(4, n_max + 1, size=batch)
         graphs = [generate_np(rng, int(n)) for n in sizes]
+        mats = [_recast_graph(g.h, sr.name) for g in graphs]
         res = solve_batch(
-            [_recast_graph(g.h, sr.name) for g in graphs], method=method,
-            with_pred=with_pred, n_max=n_max, semiring=sr,
+            mats, method=method, with_pred=with_pred, n_max=n_max, semiring=sr,
         )
         jax.block_until_ready(res.dist)
         if t_compile is None:
@@ -254,6 +259,8 @@ def serve_apsp(
             msg += f" ({(done - batch) / steady:.1f} graphs/s steady-state)"
         msg += f" (compile {t_compile:.2f}s, method={method})"
     print(msg)
+    if report is not None:
+        report.update(graphs=mats, result=res)
     return 0
 
 
@@ -279,6 +286,9 @@ def serve_apsp_dynamic(
     reader_workers: int = 0,
     durability_dir: str = "",
     checkpoint_every: int = 0,
+    rho: float = 60.0,
+    worsen_frac: float = 0.05,
+    report: Optional[dict] = None,
 ) -> int:
     """Incremental APSP serving on the supervised engine pool.
 
@@ -314,7 +324,10 @@ def serve_apsp_dynamic(
     + atomic checkpoints every ``checkpoint_every`` drains, making the
     ``crash_restore:R`` drill an end-to-end checkpoint + replay exercise.
     ``reader_workers`` sizes the sync-path deadline readers (0 = one per
-    slot).
+    slot).  ``rho`` is the graph generator's density knob and
+    ``worsen_frac`` the share of update edges that get worse (the rest
+    decrease or insert).  ``report``, when given, receives the pool
+    summary (``"summary"``) and the drift reports (``"drift"``).
     """
     import json
     import tempfile
@@ -349,7 +362,7 @@ def serve_apsp_dynamic(
     rng = np.random.default_rng(seed)
     t0 = time.time()
     for gid in range(graphs):
-        g = generate_np(rng, n_max, rho=60.0)
+        g = generate_np(rng, n_max, rho=rho)
         pool.admit(gid, _recast_graph(g.h, sr.name))
     t_warm = time.time() - t0
     print(f"[dynamic] {graphs} supervised slots of n={n_max} warmed "
@@ -369,7 +382,7 @@ def serve_apsp_dynamic(
             # worsenings (exercises the bounded re-solve)
             u, v, w = generate_edge_updates(
                 rng, slot.engine.h if slot.engine is not None else slot._h,
-                int(rng.integers(1, mutate_k + 1)), worsen_frac=0.05,
+                int(rng.integers(1, mutate_k + 1)), worsen_frac=worsen_frac,
             )
             if semiring != "tropical":
                 w = _recast_edge_weights(w, semiring)
@@ -398,12 +411,12 @@ def serve_apsp_dynamic(
                       f"{r.staleness} (shed={r.shed} "
                       f"deadline_missed={r.deadline_missed}, req {req})")
         if verify_every and (req + 1) % verify_every == 0:
-            report = pool.verify(gi)
+            check = pool.verify(gi)
             print(f"[verify] slot {gi} vs cold solve: "
-                  f"{'OK' if report['ok'] else 'DRIFT'}"
-                  + ("" if report["ok"] else f" (recovered={report['recovered']})"))
-            if not report["ok"]:
-                drift_reports.append(report)
+                  f"{'OK' if check['ok'] else 'DRIFT'}"
+                  + ("" if check["ok"] else f" (recovered={check['recovered']})"))
+            if not check["ok"]:
+                drift_reports.append(check)
     dt = time.time() - t0
     pool.recover_all(readmit=True)
 
@@ -413,6 +426,8 @@ def serve_apsp_dynamic(
           f"{n_queries} queries ({1e3 * t_query / max(n_queries, 1):.2f} ms/query)")
     print(f"[pool] {json.dumps(summary, sort_keys=True, default=str)}")
     pool.close()
+    if report is not None:
+        report.update(summary=summary, drift=drift_reports)
 
     # resilience contract: structured failure summary + non-zero exit so CI
     # can gate on drift / poison / unrecovered slots
@@ -511,6 +526,9 @@ def main(argv=None) -> int:
                          "N successful drains (0 = only the build-time "
                          "checkpoint)")
     args = ap.parse_args(argv)
+    from repro.caches import enable_compile_cache
+
+    enable_compile_cache()
     if args.arch == "mind":
         return serve_mind(args.requests, args.seed)
     if args.arch == "apsp":

@@ -18,16 +18,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:
     """with_sharding_constraint that is a no-op when no mesh is in context
-    (single-host tests / CPU examples), the spec names absent axes, or we
-    are tracing inside a legacy full-manual shard_map body (constraints are
-    illegal there; see repro.compat)."""
-    if compat.in_manual_region():
-        return x
-    mesh = compat.get_abstract_mesh()
+    (single-host tests / CPU examples) or the spec names absent axes."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return x
     flat = []

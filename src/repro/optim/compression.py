@@ -20,8 +20,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_psum", "init_error_state"]
 
 
@@ -45,7 +43,7 @@ def compressed_psum(g: jax.Array, err: jax.Array, axis: str):
     """One-leaf int8 error-feedback psum along ``axis`` (inside shard_map).
 
     Returns (reduced mean gradient f32, new error residual)."""
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     x = g.astype(jnp.float32) + err
     q, scale = quantize_int8(x)
     # int8 tensors sum in int32 to avoid overflow across <= 127*n

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.optim import clip_by_global_norm
 from repro.optim.compression import compressed_psum
 
@@ -182,7 +181,7 @@ def make_compressed_train_step(
 
         param_specs_pod = jax.tree.map(lambda _: P(), state.params)
         err_specs = jax.tree.map(lambda _: P("pod"), state.err)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             pod_body,
             mesh=mesh,
             in_specs=(param_specs_pod, err_specs, batch_spec_fn(batch)),

@@ -77,7 +77,6 @@ def test_compressed_train_step_tracks_plain():
     out = _run("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro import compat
         from repro.optim import make_optimizer, warmup_cosine
         from repro.train import (init_train_state, make_train_step,
                                  make_compressed_train_step)
@@ -97,7 +96,7 @@ def test_compressed_train_step_tracks_plain():
         step_p = make_train_step(lambda p, b: loss_fn(p, b, cfg_p), opt)
         toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 61)
         batch = {"tokens": toks, "labels": toks}
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             bsh = jax.device_put(batch, NamedSharding(mesh, P(("pod","data"), None)))
             s1 = init_train_state(params, opt, n_pods=2)
             s2 = init_train_state(params, opt)

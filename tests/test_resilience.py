@@ -247,6 +247,107 @@ def test_persistent_crash_stays_quarantined_and_requeues():
     assert float(slot.engine.h[0, 1]) == 0.5
 
 
+def _refuse_lowering(*_a, **_k):
+    raise NotImplementedError("Unimplemented primitive in lowering")
+
+
+def _refuse_compile(*_a, **_k):
+    # a real XLA compile failure: a custom call with no registered handler
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones(3)
+    jax.jit(lambda x: jax.ffi.ffi_call(
+        "repro_no_such_kernel", jax.ShapeDtypeStruct(x.shape, x.dtype))(x))(x)
+
+
+@pytest.mark.parametrize("refuse", [_refuse_lowering, _refuse_compile],
+                         ids=["lowering", "compile"])
+@pytest.mark.parametrize("batches", [1, 2])
+def test_lowering_failure_propagates_without_retry(monkeypatch, batches, refuse):
+    # a kernel the backend cannot lower or compile fails identically on
+    # every attempt: it must surface to the caller, not be retried,
+    # quarantined and papered over with snapshot answers (single and
+    # coalesced drains) — and the batches stay queued, so staleness is exact
+    pool = make_pool(max_retries=3)
+    slot = pool.slots[0]
+    monkeypatch.setattr(slot.engine, "update", refuse)
+    for b in range(batches):
+        pool.submit_update(0, [0], [1 + b], [0.5])
+    with pytest.raises(RuntimeError):
+        pool.drain(0)
+    assert slot.stats["retries"] == 0
+    assert slot.stats["quarantines"] == 0
+    assert pool.stats["updates_failed"] == 0
+    assert slot.state == SlotState.HEALTHY
+    assert len(slot.pending) == batches and slot._inflight == 0
+    assert slot.staleness() == batches
+    with pytest.raises(RuntimeError):                # no answer hides it
+        pool.query(0, np.array([0]), np.array([1]))
+    assert len(slot.pending) == batches
+
+
+def test_execution_runtime_error_stays_transient(monkeypatch):
+    # an XLA error raised while *running* (e.g. an exhausted allocator) is
+    # not a compile failure: it keeps bounded retry + quarantine
+    import jax
+
+    pool = make_pool(max_retries=1)
+    slot = pool.slots[0]
+
+    def oom(*_a, **_k):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    monkeypatch.setattr(slot.engine, "update", oom)
+    monkeypatch.setattr(slot, "recover", lambda: False)
+    pool.submit_update(0, [0], [1], [0.5])
+    infos = pool.drain(0)
+    assert infos[0]["path"] == "failed"
+    assert slot.stats["retries"] == 2 and slot.stats["quarantines"] == 1
+    assert len(slot.pending) == 1
+
+
+def test_compile_failure_requeues_batched_drain(monkeypatch):
+    # the stacked cross-graph drain: a program that fails to compile puts
+    # every popped batch back before the error surfaces
+    import repro.launch.pool as pool_mod
+
+    pool = make_pool(graphs=2)
+    monkeypatch.setattr(pool_mod, "apply_updates_batched",
+                        lambda *_a, **_k: _refuse_compile())
+    for gid in range(2):
+        pool.submit_update(gid, [0], [1], [0.5])
+    with pytest.raises(RuntimeError):
+        pool.drain_all()
+    for gid in range(2):
+        slot = pool.slots[gid]
+        assert len(slot.pending) == 1 and slot._inflight == 0
+        assert slot.staleness() == 1 and slot.state == SlotState.HEALTHY
+
+
+def test_compile_failure_reaches_async_caller(monkeypatch):
+    # async pools: the worker neither swallows the failure nor spins on the
+    # slot; the next flush re-raises it and the batch stays queued
+    pool = make_pool(async_updates=True)
+    try:
+        slot = pool.slots[0]
+        monkeypatch.setattr(slot.engine, "update", _refuse_compile)
+        pool.submit_update(0, [0], [1], [0.5])
+        with pytest.raises(RuntimeError):
+            pool.flush(timeout=60.0)
+        assert pool.executor.flush(timeout=60.0)    # raised once, no spin
+        assert pool.executor.stats["drain_errors"] == 1
+        assert pool.executor.stats["requeues"] == 0
+        assert len(slot.pending) == 1 and slot.staleness() == 1
+        assert slot.stats["retries"] == 0 and slot.state == SlotState.HEALTHY
+        r = pool.query(0, np.array([0]), np.array([1]))
+        assert r.source == "snapshot" and r.staleness == 1
+        with pytest.raises(RuntimeError):           # persistent: every barrier
+            pool.flush(timeout=60.0)
+    finally:
+        pool.close()
+
+
 def test_injected_nan_update_rejected_slot_stays_healthy():
     inj = FaultInjector(FaultSpec(nan=1.0), seed=0)
     pool = make_pool(injector=inj)
