@@ -58,6 +58,10 @@ from .floyd_warshall import (
 )
 from .rkleene import rkleene
 from .semiring import TROPICAL, Semiring, SemiringLike, get_semiring
+from .spans import (
+    DISPATCH, FETCH, NEGCYCLE, PACK, SCATTER, SOLVE, SOLVE_BATCH, VALIDATE,
+    span,
+)
 
 __all__ = [
     "APSPResult",
@@ -123,6 +127,20 @@ def check_negative_cycles(
             "below.  Remove the cycle or pass validate=False to skip this "
             "check (the returned matrix would be meaningless)."
         )
+
+
+def _on_device(a) -> bool:
+    return isinstance(a, jax.Array)
+
+
+def _device_bytes(a) -> int:
+    """Bytes a host read of ``a`` copies off the device (0 for host data)."""
+    return a.nbytes if _on_device(a) else 0
+
+
+def _negcycle_bytes(dist, semiring: Semiring) -> int:
+    """Bytes :func:`check_negative_cycles` copies to the host."""
+    return _device_bytes(dist) if semiring.name == "tropical" else 0
 
 
 @dataclass
@@ -276,16 +294,21 @@ def solve(
     if method not in METHODS:
         raise ValueError(f"unknown APSP method {method!r}; have {sorted(METHODS)}")
     sr = get_semiring(semiring)
-    if validate:
-        validate_cost_matrix(h, sr)
-    target = jnp.float32 if dtype is None else jnp.dtype(dtype)
-    x = jnp.asarray(h, target)
-    if donate is None:
-        donate = x is not h               # fresh copy -> safe to consume
-    dist, pred = METHODS[method](x, with_pred, semiring=sr, donate=donate,
-                                 **kwargs)
-    if validate:
-        check_negative_cycles(dist, sr)
+    with span(SOLVE, n=np.shape(h)[-1]):
+        if validate:
+            with span(VALIDATE, bytes_to_host=_device_bytes(h)):
+                validate_cost_matrix(h, sr)
+        target = jnp.float32 if dtype is None else jnp.dtype(dtype)
+        with span(DISPATCH) as sp:
+            x = jnp.asarray(h, target)
+            if donate is None:
+                donate = x is not h               # fresh copy -> safe to consume
+            dist, pred = METHODS[method](x, with_pred, semiring=sr,
+                                         donate=donate, **kwargs)
+            sp.set_metadata(bytes_to_device=0 if _on_device(h) else x.nbytes)
+        if validate:
+            with span(NEGCYCLE, bytes_to_host=_negcycle_bytes(dist, sr)):
+                check_negative_cycles(dist, sr)
     return APSPResult(dist=dist, pred=pred, method=method)
 
 
@@ -381,17 +404,34 @@ def _bucket_count(c: int) -> int:
     return -(-c // 8) * 8
 
 
+def _input_device_bytes(hs) -> int:
+    """Bytes a host read of every graph of a batch copies off the device."""
+    if hasattr(hs, "ndim"):
+        return _device_bytes(hs)
+    return sum(_device_bytes(m) for m in hs)
+
+
+def _group_by_edge(sizes: np.ndarray) -> Dict[int, List[int]]:
+    """Graph indices grouped by padded bucket edge."""
+    buckets: Dict[int, List[int]] = {}
+    for i, k in enumerate(sizes):
+        buckets.setdefault(_bucket_edge(int(k)), []).append(i)
+    return buckets
+
+
 def _solve_bucketed(
-    mats: List[np.ndarray], sizes: np.ndarray, n: int, method: str,
-    with_pred: bool, semiring=TROPICAL, donate=True, dtype=None, **kwargs
+    mats: List[np.ndarray], sizes: np.ndarray, buckets: Dict[int, List[int]],
+    n: int, method: str, with_pred: bool, semiring=TROPICAL, donate=True,
+    dtype=None, **kwargs
 ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Size-bucketed batched solve: graphs grouped by power-of-two padded
-    edge, one batched program per bucket, results scattered back into the
-    common (G, n, n) frame.  Bit-identical to the single-stack path —
-    padding is inert either way — but a ragged corpus does ~size^3 work per
-    graph instead of n_max^3.  Per-bucket stacks are fresh, so they donate
-    unless the caller opted out; ``dtype`` casts each bucket's stack (bf16
-    mixed mode) and the scattered result frame."""
+    edge (``buckets``, from :func:`_group_by_edge`), one batched program
+    per bucket, results scattered back into the common (G, n, n) frame.
+    Bit-identical to the single-stack path — padding is inert either way —
+    but a ragged corpus does ~size^3 work per graph instead of n_max^3.
+    Per-bucket stacks are fresh, so they donate unless the caller opted
+    out; ``dtype`` casts each bucket's stack (bf16 mixed mode) and the
+    scattered result frame."""
     g = len(mats)
     out_dtype = np.float32 if dtype is None else jnp.dtype(dtype)
     dist = np.full((g, n, n), semiring.zero, out_dtype)
@@ -402,28 +442,33 @@ def _solve_bucketed(
         pred = np.full((g, n, n), -1, np.int32)
         pred[:, idx, idx] = idx
 
-    buckets: Dict[int, List[int]] = {}
-    for i, k in enumerate(sizes):
-        buckets.setdefault(_bucket_edge(int(k)), []).append(i)
-
     for edge, members in sorted(buckets.items()):
         slots = _bucket_count(len(members))
         sub = [mats[i] for i in members]
         sub += [np.zeros((0, 0), np.float32)] * (slots - len(members))
-        stack, _ = pad_batch(sub, n_max=edge, semiring=semiring)
-        if dtype is not None:
-            stack = stack.astype(jnp.dtype(dtype))
-        # pad_batch built a fresh stack -> safe to donate per bucket
-        d, p = _solve_stack(stack, with_pred, method, semiring=semiring,
-                            donate=donate, **kwargs)
-        d = np.asarray(d)
-        p = None if p is None else np.asarray(p)
-        for j, i in enumerate(members):
-            k = int(sizes[i])
-            dist[i, :k, :k] = d[j, :k, :k]
-            if with_pred:
-                pred[i, :k, :k] = p[j, :k, :k]
-    return jnp.asarray(dist), None if pred is None else jnp.asarray(pred)
+        with span(PACK, edge=edge, graphs=len(members), slots=slots,
+                  useful_work=int(np.sum(sizes[members] ** 3)),
+                  padded_work=slots * edge ** 3) as pack:
+            stack, _ = pad_batch(sub, n_max=edge, semiring=semiring)
+            pack.set_metadata(bytes_to_device=stack.nbytes)
+            if dtype is not None:
+                stack = stack.astype(jnp.dtype(dtype))
+        with span(DISPATCH):
+            # pad_batch built a fresh stack -> safe to donate per bucket
+            d, p = _solve_stack(stack, with_pred, method, semiring=semiring,
+                                donate=donate, **kwargs)
+        with span(FETCH, bytes_to_host=d.nbytes + (0 if p is None else p.nbytes)):
+            d = np.asarray(d)
+            p = None if p is None else np.asarray(p)
+        with span(SCATTER):
+            for j, i in enumerate(members):
+                k = int(sizes[i])
+                dist[i, :k, :k] = d[j, :k, :k]
+                if with_pred:
+                    pred[i, :k, :k] = p[j, :k, :k]
+    upload = dist.nbytes + (0 if pred is None else pred.nbytes)
+    with span(SCATTER, bytes_to_device=upload):
+        return jnp.asarray(dist), None if pred is None else jnp.asarray(pred)
 
 
 def solve_batch(
@@ -466,41 +511,63 @@ def solve_batch(
     if method not in METHODS:
         raise ValueError(f"unknown APSP method {method!r}; have {sorted(METHODS)}")
     semiring = get_semiring(semiring)
-    if validate:
-        if hasattr(hs, "ndim"):
-            validate_cost_matrix(hs, semiring)
-        else:
-            for m in hs:
-                validate_cost_matrix(m, semiring)
-    if bucket_by_size:
-        if hasattr(hs, "ndim") and hs.ndim == 3:
-            mats = [np.asarray(h) for h in hs]
-            sizes_ = (np.full(len(mats), hs.shape[1], np.int64)
-                      if sizes is None else np.asarray(sizes, np.int64))
-            mats = [m[:k, :k] for m, k in zip(mats, sizes_)]
-        else:
-            mats = [np.asarray(h) for h in hs]
-            sizes_ = (np.array([m.shape[0] for m in mats], np.int64)
-                      if sizes is None else np.asarray(sizes, np.int64))
-        if not mats:
-            raise ValueError("empty graph batch")
-        n = int(max(sizes_.max(), 1)) if n_max is None else int(n_max)
-        if int(sizes_.max()) > n:
-            raise ValueError(f"n_max={n} smaller than largest graph")
-        dist, pred = _solve_bucketed(
-            mats, sizes_, n, method, with_pred, semiring=semiring,
-            donate=donate is not False, dtype=dtype, **kwargs
-        )
+    with span(SOLVE_BATCH, graphs=len(hs)) as call:
         if validate:
-            check_negative_cycles(dist, semiring, sizes=sizes_)
-        return BatchAPSPResult(dist=dist, pred=pred, sizes=sizes_, method=method)
-    stack, sizes = pad_batch(hs, sizes, n_max=n_max, semiring=semiring)
-    if dtype is not None:
-        stack = stack.astype(jnp.dtype(dtype))
-    if donate is None:
-        donate = stack is not hs          # fresh packed stack -> consume it
-    dist, pred = _solve_stack(stack, with_pred, method, semiring=semiring,
-                              donate=donate, **kwargs)
-    if validate:
-        check_negative_cycles(dist, semiring, sizes=sizes)
+            with span(VALIDATE, graphs=len(hs),
+                      bytes_to_host=_input_device_bytes(hs)):
+                if hasattr(hs, "ndim"):
+                    validate_cost_matrix(hs, semiring)
+                else:
+                    for m in hs:
+                        validate_cost_matrix(m, semiring)
+        if bucket_by_size:
+            with span(FETCH, bytes_to_host=_input_device_bytes(hs)):
+                if hasattr(hs, "ndim") and hs.ndim == 3:
+                    mats = [np.asarray(h) for h in hs]
+                    sizes_ = (np.full(len(mats), hs.shape[1], np.int64)
+                              if sizes is None else np.asarray(sizes, np.int64))
+                    mats = [m[:k, :k] for m, k in zip(mats, sizes_)]
+                else:
+                    mats = [np.asarray(h) for h in hs]
+                    sizes_ = (np.array([m.shape[0] for m in mats], np.int64)
+                              if sizes is None else np.asarray(sizes, np.int64))
+            if not mats:
+                raise ValueError("empty graph batch")
+            n = int(max(sizes_.max(), 1)) if n_max is None else int(n_max)
+            if int(sizes_.max()) > n:
+                raise ValueError(f"n_max={n} smaller than largest graph")
+            buckets = _group_by_edge(sizes_)
+            call.set_metadata(buckets=len(buckets))
+            dist, pred = _solve_bucketed(
+                mats, sizes_, buckets, n, method, with_pred, semiring=semiring,
+                donate=donate is not False, dtype=dtype, **kwargs
+            )
+            if validate:
+                with span(NEGCYCLE, bytes_to_host=_negcycle_bytes(dist, semiring)):
+                    check_negative_cycles(dist, semiring, sizes=sizes_)
+            return BatchAPSPResult(dist=dist, pred=pred, sizes=sizes_,
+                                   method=method)
+        call.set_metadata(buckets=1)
+        with span(PACK) as pack:
+            stack, sizes = pad_batch(hs, sizes, n_max=n_max, semiring=semiring)
+            # a full-size device stack goes straight to the solver; any
+            # other input was read on the host and uploaded
+            direct = (_on_device(hs) and stack.shape == hs.shape
+                      and bool((sizes == hs.shape[1]).all()))
+            g, edge = stack.shape[0], stack.shape[-1]
+            pack.set_metadata(
+                edge=edge, graphs=len(sizes), slots=g,
+                useful_work=int(np.sum(sizes ** 3)), padded_work=g * edge ** 3,
+                bytes_to_host=0 if direct else _input_device_bytes(hs),
+                bytes_to_device=0 if direct else stack.nbytes)
+            if dtype is not None:
+                stack = stack.astype(jnp.dtype(dtype))
+        if donate is None:
+            donate = stack is not hs          # fresh packed stack -> consume it
+        with span(DISPATCH):
+            dist, pred = _solve_stack(stack, with_pred, method,
+                                      semiring=semiring, donate=donate, **kwargs)
+        if validate:
+            with span(NEGCYCLE, bytes_to_host=_negcycle_bytes(dist, semiring)):
+                check_negative_cycles(dist, semiring, sizes=sizes)
     return BatchAPSPResult(dist=dist, pred=pred, sizes=sizes, method=method)

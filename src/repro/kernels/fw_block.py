@@ -101,6 +101,7 @@ def _closure_call(d, p, *, interpret: bool, semiring: Semiring):
             if pred else out_shape
         ),
         interpret=interpret,
+        name="fw_block_pred" if pred else "fw_block",
         **params,
     )(*((d, p) if pred else (d,)))
 

@@ -131,6 +131,7 @@ def fw_round_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, n, n), storage),
         interpret=interpret,
+        name="fw_round",
         **params,
     )(t, pivot, colpan, dd, dd)
     return out if batched else out[0]

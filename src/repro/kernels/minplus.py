@@ -214,7 +214,7 @@ def _specs(batched: bool, bm: int, bn: int, bk: int):
     )
 
 
-def _grid_call(kernel, grid, in_specs, out_specs, out_shape, interpret):
+def _grid_call(kernel, grid, in_specs, out_specs, out_shape, interpret, name):
     params = {}
     if not interpret:
         # batch/m/n blocks are independent; k must stay sequential
@@ -229,6 +229,7 @@ def _grid_call(kernel, grid, in_specs, out_specs, out_shape, interpret):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
         **params,
     )
 
@@ -334,13 +335,16 @@ def _call(x, y, a, *, bm, bn, bk, kc, accumulate, track, interpret, sr):
         ap = _pad(a, z_spec.block_shape[-2], z_spec.block_shape[-1], sr.zero)
         in_specs, operands = [z_spec] + in_specs, [ap] + operands
     z_shape = jax.ShapeDtypeStruct(out_dims, x.dtype)
+    # one name per variant, so each shows apart in a device trace
+    name = "minplus" + "_argmin" * track + "_acc" * accumulate
     if track:
         i_shape = jax.ShapeDtypeStruct(out_dims, jnp.int32)
         fn = _grid_call(kern, grid, in_specs, (z_spec, z_spec),
-                        (z_shape, i_shape), interpret)
+                        (z_shape, i_shape), interpret, name)
         zp, ip = fn(*operands)
         return zp[..., :m, :n], ip[..., :m, :n]
-    zp = _grid_call(kern, grid, in_specs, z_spec, z_shape, interpret)(*operands)
+    zp = _grid_call(kern, grid, in_specs, z_spec, z_shape, interpret,
+                    name)(*operands)
     return zp[..., :m, :n], None
 
 

@@ -131,6 +131,7 @@ def row_close_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="row_close_track" if track else "row_close",
         **params,
     )(rows.astype(jnp.int32), dx, dy, da)
     if track:

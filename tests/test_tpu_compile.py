@@ -14,6 +14,7 @@ test file.  The fixture skips where no topology can be described.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -61,6 +62,14 @@ def no_compile_cache():
 
 def _compile(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernel_names(hlo: str) -> set:
+    """Instruction names of the compiled program's Pallas kernels, without
+    the numeric suffix: what a device trace names each kernel by."""
+    return set(re.findall(
+        r"%([\w-]+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        hlo))
 
 
 def _kernels():
@@ -128,3 +137,27 @@ def test_distributed_fw_compiles_for_v5e_2x2(topo, no_compile_cache,
     compiled = fw_distributed.lower(x, mesh=mesh, block_size=512).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().argument_size_in_bytes == N * N * 4 // 4
+
+
+def test_blocked_solve_names_its_kernels(one_chip, no_compile_cache,
+                                         monkeypatch):
+    # the pivot closure and the stripe grid of one fused round carry their
+    # own names, not the name of the function that encloses them
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    from repro.core.blocked_fw import blocked_fw
+
+    x = jax.ShapeDtypeStruct((2048, 2048), jnp.float32, sharding=one_chip)
+    hlo = _compile(lambda d: blocked_fw(d, block_size=BLOCK,
+                                        round_mode="fused")[0], x)
+    assert _kernel_names(hlo) == {"fw_block", "fw_round"}
+
+
+def test_minplus_variants_have_distinct_names(one_chip, no_compile_cache):
+    from repro.kernels.minplus import minplus_argmin_pallas, minplus_pallas
+
+    a = jax.ShapeDtypeStruct((512, 512), jnp.float32, sharding=one_chip)
+    names = [_kernel_names(_compile(
+        lambda x, y, z, f=f, acc=acc: f(x, y, z, accumulate=acc), a, a, a))
+        for f in (minplus_pallas, minplus_argmin_pallas) for acc in (False, True)]
+    assert names == [{"minplus"}, {"minplus_acc"}, {"minplus_argmin"},
+                     {"minplus_argmin_acc"}]
