@@ -77,15 +77,29 @@ __all__ = [
 ]
 
 
+@jax.jit
+def _any_nan(h):
+    return jnp.isnan(h).any()
+
+
+@jax.jit
+def _diagonal(d):
+    return jnp.diagonal(d, axis1=-2, axis2=-1)
+
+
 def validate_cost_matrix(h, semiring: SemiringLike = "tropical") -> None:
     """Input-boundary contract check shared by ``solve`` / ``solve_batch`` /
     ``DynamicAPSP``: NaN entries are rejected with a typed
     :class:`~repro.core.errors.InputValidationError` *before* any dispatch —
     a NaN is absorbing under every registered ⊕/⊗ pair, so one poisoned
     entry silently corrupts the whole closure.  Works on a single (n, n)
-    matrix or a (G, n, n) stack; host-side (syncs a device input — pass
-    ``validate=False`` at the entry points on hot paths that already
-    guarantee clean inputs)."""
+    matrix or a (G, n, n) stack.  Every entry is scanned: a device input by
+    one reduction on the device, whose one-byte verdict is all that comes
+    to the host (the whole input only when it holds a NaN, to name the
+    first); a host input on the host.  Pass ``validate=False`` at the entry
+    points on hot paths that already guarantee clean inputs."""
+    if _on_device(h) and not bool(_any_nan(h)):
+        return
     a = np.asarray(h)
     bad = np.isnan(a)
     if bad.any():
@@ -111,11 +125,13 @@ def check_negative_cycles(
     is exact: negative *edges* are fine, only a closed negative *walk*
     drives ``dist[i, i]`` below the diagonal's one (0).  Accepts (n, n) or
     (G, n, n); ``sizes`` restricts each graph's check to its true block
-    (padding diagonals are the semiring one by construction)."""
+    (padding diagonals are the semiring one by construction).  Only the
+    diagonal is read: a device ``dist`` has it gathered on the device and
+    copies those n (or G·n) entries to the host, nothing else."""
     if semiring.name != "tropical":
         return
-    d = np.asarray(dist)
-    diag = np.diagonal(d, axis1=-2, axis2=-1)
+    diag = np.asarray(_diagonal(dist) if _on_device(dist)
+                      else np.diagonal(dist, axis1=-2, axis2=-1))
     neg = diag < 0
     if sizes is not None:
         neg = neg & (np.arange(diag.shape[-1]) < np.asarray(sizes)[:, None])
@@ -123,9 +139,9 @@ def check_negative_cycles(
         idx = tuple(int(x) for x in np.argwhere(neg)[0])
         raise NegativeCycleError(
             f"negative cycle detected: solved diagonal entry {idx} is "
-            f"{diag[neg].min():g} < 0, so tropical distances are unbounded "
-            "below.  Remove the cycle or pass validate=False to skip this "
-            "check (the returned matrix would be meaningless)."
+            f"{float(diag[neg].min()):g} < 0, so tropical distances are "
+            "unbounded below.  Remove the cycle or pass validate=False to "
+            "skip this check (the returned matrix would be meaningless)."
         )
 
 
@@ -138,9 +154,21 @@ def _device_bytes(a) -> int:
     return a.nbytes if _on_device(a) else 0
 
 
-def _negcycle_bytes(dist, semiring: Semiring) -> int:
-    """Bytes :func:`check_negative_cycles` copies to the host."""
-    return _device_bytes(dist) if semiring.name == "tropical" else 0
+def _validate_counts(hs) -> Dict[str, int]:
+    """Span counts of :func:`validate_cost_matrix` over ``hs`` (one matrix
+    or stack, or a list of them) that holds no NaN: each device input sends
+    its one-byte verdict to the host; a host input moves nothing."""
+    on = sum(map(_on_device, [hs] if hasattr(hs, "ndim") else hs))
+    return {"bytes_to_host": on, "on_device": on}
+
+
+def _negcycle_counts(dist, semiring: Semiring) -> Dict[str, int]:
+    """Span counts of :func:`check_negative_cycles`: a device ``dist``'s
+    diagonal is what crosses to the host (tropical only)."""
+    if semiring.name != "tropical" or not _on_device(dist):
+        return {"bytes_to_host": 0, "on_device": 0}
+    diag = dist.nbytes // dist.shape[-1]
+    return {"bytes_to_host": diag, "on_device": 1}
 
 
 @dataclass
@@ -288,15 +316,18 @@ def solve(
     ``validate`` (default True): reject NaN input entries with a typed
     ``InputValidationError`` before dispatch, and (tropical only) raise
     ``NegativeCycleError`` when the solved diagonal goes negative instead
-    of returning meaningless distances.  Both checks sync the host; pass
-    ``validate=False`` on hot paths with guaranteed-clean inputs.
+    of returning meaningless distances.  A device input is scanned on the
+    device and only the verdict comes back; the negative-cycle check copies
+    the solved diagonal alone (n entries) to the host.  Both checks wait
+    for their device result; pass ``validate=False`` on hot paths with
+    guaranteed-clean inputs.
     """
     if method not in METHODS:
         raise ValueError(f"unknown APSP method {method!r}; have {sorted(METHODS)}")
     sr = get_semiring(semiring)
     with span(SOLVE, n=np.shape(h)[-1]):
         if validate:
-            with span(VALIDATE, bytes_to_host=_device_bytes(h)):
+            with span(VALIDATE, **_validate_counts(h)):
                 validate_cost_matrix(h, sr)
         target = jnp.float32 if dtype is None else jnp.dtype(dtype)
         with span(DISPATCH) as sp:
@@ -307,7 +338,7 @@ def solve(
                                          donate=donate, **kwargs)
             sp.set_metadata(bytes_to_device=0 if _on_device(h) else x.nbytes)
         if validate:
-            with span(NEGCYCLE, bytes_to_host=_negcycle_bytes(dist, sr)):
+            with span(NEGCYCLE, **_negcycle_counts(dist, sr)):
                 check_negative_cycles(dist, sr)
     return APSPResult(dist=dist, pred=pred, method=method)
 
@@ -423,10 +454,11 @@ def _solve_bucketed(
     mats: List[np.ndarray], sizes: np.ndarray, buckets: Dict[int, List[int]],
     n: int, method: str, with_pred: bool, semiring=TROPICAL, donate=True,
     dtype=None, **kwargs
-) -> Tuple[jax.Array, Optional[jax.Array]]:
+) -> Tuple[np.ndarray, Optional[np.ndarray]]:
     """Size-bucketed batched solve: graphs grouped by power-of-two padded
     edge (``buckets``, from :func:`_group_by_edge`), one batched program
-    per bucket, results scattered back into the common (G, n, n) frame.
+    per bucket, results scattered back into the common (G, n, n) frame,
+    which stays on the host for the caller to check and upload.
     Bit-identical to the single-stack path — padding is inert either way —
     but a ragged corpus does ~size^3 work per graph instead of n_max^3.
     Per-bucket stacks are fresh, so they donate unless the caller opted
@@ -466,9 +498,7 @@ def _solve_bucketed(
                 dist[i, :k, :k] = d[j, :k, :k]
                 if with_pred:
                     pred[i, :k, :k] = p[j, :k, :k]
-    upload = dist.nbytes + (0 if pred is None else pred.nbytes)
-    with span(SCATTER, bytes_to_device=upload):
-        return jnp.asarray(dist), None if pred is None else jnp.asarray(pred)
+    return dist, pred
 
 
 def solve_batch(
@@ -506,15 +536,16 @@ def solve_batch(
     the natively-batched in-place solvers; ``dtype=jnp.bfloat16`` selects
     mixed precision (tropical only).  ``validate`` follows :func:`solve`
     (NaN rejection per graph + tropical negative-cycle detection on each
-    unpadded diagonal; ``validate=False`` to skip on hot paths).
+    unpadded diagonal; ``validate=False`` to skip on hot paths).  The
+    size-bucketed path checks the diagonals on its host frame before the
+    upload, so nothing is read back.
     """
     if method not in METHODS:
         raise ValueError(f"unknown APSP method {method!r}; have {sorted(METHODS)}")
     semiring = get_semiring(semiring)
     with span(SOLVE_BATCH, graphs=len(hs)) as call:
         if validate:
-            with span(VALIDATE, graphs=len(hs),
-                      bytes_to_host=_input_device_bytes(hs)):
+            with span(VALIDATE, graphs=len(hs), **_validate_counts(hs)):
                 if hasattr(hs, "ndim"):
                     validate_cost_matrix(hs, semiring)
                 else:
@@ -542,9 +573,13 @@ def solve_batch(
                 mats, sizes_, buckets, n, method, with_pred, semiring=semiring,
                 donate=donate is not False, dtype=dtype, **kwargs
             )
-            if validate:
-                with span(NEGCYCLE, bytes_to_host=_negcycle_bytes(dist, semiring)):
+            if validate:       # on the host frame, before its upload
+                with span(NEGCYCLE, **_negcycle_counts(dist, semiring)):
                     check_negative_cycles(dist, semiring, sizes=sizes_)
+            upload = dist.nbytes + (0 if pred is None else pred.nbytes)
+            with span(SCATTER, bytes_to_device=upload):
+                dist = jnp.asarray(dist)
+                pred = None if pred is None else jnp.asarray(pred)
             return BatchAPSPResult(dist=dist, pred=pred, sizes=sizes_,
                                    method=method)
         call.set_metadata(buckets=1)
@@ -568,6 +603,6 @@ def solve_batch(
             dist, pred = _solve_stack(stack, with_pred, method,
                                       semiring=semiring, donate=donate, **kwargs)
         if validate:
-            with span(NEGCYCLE, bytes_to_host=_negcycle_bytes(dist, semiring)):
+            with span(NEGCYCLE, **_negcycle_counts(dist, semiring)):
                 check_negative_cycles(dist, semiring, sizes=sizes)
     return BatchAPSPResult(dist=dist, pred=pred, sizes=sizes, method=method)

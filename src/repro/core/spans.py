@@ -12,9 +12,9 @@ import jax
 
 SOLVE = "apsp.solve"              # one solve call; counts n
 SOLVE_BATCH = "apsp.solve_batch"  # one solve_batch call; counts graphs, buckets
-VALIDATE = "apsp.validate"        # NaN scan of the input; bytes_to_host
+VALIDATE = "apsp.validate"        # NaN scan of the input; bytes_to_host, on_device
 DISPATCH = "apsp.dispatch"        # cast and solver enqueue
-NEGCYCLE = "apsp.negcycle"        # solved-diagonal check; bytes_to_host
+NEGCYCLE = "apsp.negcycle"        # solved-diagonal check; bytes_to_host, on_device
 PACK = "apsp.pack"                # one bucket's padded stack; work and bytes
 FETCH = "apsp.fetch"              # device results or inputs copied to the host
 SCATTER = "apsp.scatter"          # results copied into the frame, frame upload

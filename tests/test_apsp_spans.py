@@ -62,19 +62,22 @@ def test_solve_spans_and_counts(tmp_path):
         (S.NEGCYCLE, S.SOLVE)]
     counts = {s[2]: s[3] for s in got}
     assert counts[S.SOLVE] == {"n": n}
-    assert counts[S.VALIDATE] == {"bytes_to_host": n * n * 4}
+    # the NaN scan runs on the device: its one-byte verdict crosses
+    assert counts[S.VALIDATE] == {"bytes_to_host": 1, "on_device": 1}
     assert counts[S.DISPATCH] == {"bytes_to_device": 0}     # already on device
-    assert counts[S.NEGCYCLE] == {"bytes_to_host": n * n * 4}
+    # the negative-cycle check reads the solved diagonal alone
+    assert counts[S.NEGCYCLE] == {"bytes_to_host": n * 4, "on_device": 1}
     moved = sum(c.get("bytes_to_host", 0) for c in counts.values())
-    assert moved == 2 * n * n * 4
+    assert moved == 1 + n * 4
 
 
 def test_solve_host_input_counts_its_upload(tmp_path):
     h = _ragged([12])[0]
     got = _traced(tmp_path, lambda: solve(h))
     counts = {s[2]: s[3] for s in got}
-    assert counts[S.VALIDATE] == {"bytes_to_host": 0}
+    assert counts[S.VALIDATE] == {"bytes_to_host": 0, "on_device": 0}
     assert counts[S.DISPATCH] == {"bytes_to_device": 12 * 12 * 4}
+    assert counts[S.NEGCYCLE] == {"bytes_to_host": 12 * 4, "on_device": 1}
 
 
 def test_bucketed_solve_batch_spans_and_counts(tmp_path):
@@ -85,10 +88,10 @@ def test_bucketed_solve_batch_spans_and_counts(tmp_path):
     per_bucket = [S.PACK, S.DISPATCH, S.FETCH, S.SCATTER]
     assert [s[2] for s in got] == (
         [S.SOLVE_BATCH, S.VALIDATE, S.FETCH] + per_bucket * 3
-        + [S.SCATTER, S.NEGCYCLE])
+        + [S.NEGCYCLE, S.SCATTER])
     assert all(s[4] == S.SOLVE_BATCH for s in got[1:])
     assert got[0][3] == {"graphs": 5, "buckets": 3}
-    assert got[1][3] == {"graphs": 5, "bytes_to_host": 0}
+    assert got[1][3] == {"graphs": 5, "bytes_to_host": 0, "on_device": 0}
     packs = [s[3] for s in got if s[2] == S.PACK]
     assert [(p["edge"], p["graphs"], p["slots"]) for p in packs] == [
         (8, 1, 1), (16, 2, 2), (32, 2, 2)]
@@ -98,9 +101,9 @@ def test_bucketed_solve_batch_spans_and_counts(tmp_path):
         4 * 8 * 8, 4 * 2 * 16 * 16, 4 * 2 * 32 * 32]
     fetches = [s[3]["bytes_to_host"] for s in got if s[2] == S.FETCH]
     assert fetches == [0, 4 * 8 * 8, 4 * 2 * 16 * 16, 4 * 2 * 32 * 32]
-    frame = 5 * 30 * 30 * 4
-    assert got[-2][3] == {"bytes_to_device": frame}
-    assert got[-1][3] == {"bytes_to_host": frame}
+    # the host frame is checked before its upload: nothing is read back
+    assert got[-2][3] == {"bytes_to_host": 0, "on_device": 0}
+    assert got[-1][3] == {"bytes_to_device": 5 * 30 * 30 * 4}
 
 
 def test_stacked_solve_batch_spans_and_counts(tmp_path):
@@ -114,12 +117,14 @@ def test_stacked_solve_batch_spans_and_counts(tmp_path):
         (S.PACK, S.SOLVE_BATCH), (S.DISPATCH, S.SOLVE_BATCH),
         (S.NEGCYCLE, S.SOLVE_BATCH)]
     assert got[0][3] == {"graphs": 3, "buckets": 1}
+    assert got[1][3] == {"graphs": 3, "bytes_to_host": 1, "on_device": 1}
     # a ragged device stack is read on the host and packed again
     assert got[2][3] == {"edge": 20, "graphs": 3, "slots": 3,
                          "useful_work": sum(k ** 3 for k in sizes),
                          "padded_work": 3 * 20 ** 3,
                          "bytes_to_host": stack.nbytes,
                          "bytes_to_device": stack.nbytes}
+    assert got[4][3] == {"bytes_to_host": 3 * 20 * 4, "on_device": 1}
 
 
 @pytest.mark.parametrize("bucket_by_size", [True, False])

@@ -10,6 +10,7 @@ eviction + deterministic re-admission, deadline misses, backlog shedding,
 and drift detection with re-solve-on-drift.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -22,7 +23,9 @@ from repro.core import (
     solve,
     solve_batch,
 )
+from repro.core.apsp import check_negative_cycles
 from repro.core.graphgen import generate_edge_updates, generate_np
+from repro.core.semiring import TROPICAL
 from repro.launch.faults import FaultInjector, FaultSpec, InjectedCrash
 from repro.launch.pool import EnginePool, EngineSlot, SlotState
 
@@ -37,11 +40,17 @@ def graph(n=16, seed=0):
 # satellite 1: typed validation at the solve boundary
 # ---------------------------------------------------------------------------
 
-def test_solve_rejects_nan_input():
+# every contract check runs on host (numpy) and device (jax) inputs alike
+HOST_AND_DEVICE = pytest.mark.parametrize(
+    "put", [np.asarray, jnp.asarray], ids=["host", "device"])
+
+
+@HOST_AND_DEVICE
+def test_solve_rejects_nan_input(put):
     h = graph()
     h[2, 3] = np.nan
     with pytest.raises(InputValidationError, match=r"NaN.*\(2, 3\)"):
-        solve(h)
+        solve(put(h))
 
 
 def test_solve_validate_false_escape_hatch():
@@ -51,37 +60,99 @@ def test_solve_validate_false_escape_hatch():
     assert np.isnan(np.asarray(r.dist)).any()
 
 
-def test_solve_detects_negative_cycle():
+@HOST_AND_DEVICE
+def test_solve_detects_negative_cycle(put):
     h = graph(8)
     h[1, 2], h[2, 1] = -5.0, 2.0          # closed walk of weight -3
     with pytest.raises(NegativeCycleError, match="negative cycle"):
-        solve(h)
-    r = solve(h, validate=False)          # diagnostic access still possible
+        solve(put(h))
+    r = solve(put(h), validate=False)     # diagnostic access still possible
     assert float(np.asarray(r.dist)[1, 1]) < 0
 
 
-def test_negative_edges_without_cycle_pass():
+@HOST_AND_DEVICE
+def test_solve_bf16_detects_negative_cycle(put):
+    h = graph(8)
+    h[1, 2], h[2, 1] = -5.0, 2.0
+    with pytest.raises(NegativeCycleError, match=r"entry \(1,\) is -9 < 0"):
+        solve(put(h), dtype=jnp.bfloat16)
+
+
+@HOST_AND_DEVICE
+def test_negative_edges_without_cycle_pass(put):
     h = np.full((4, 4), np.inf, np.float32)
     np.fill_diagonal(h, 0.0)
     h[0, 1], h[1, 2], h[2, 3] = -1.0, -2.0, 4.0   # DAG: no cycle at all
-    d = np.asarray(solve(h).dist)
+    d = np.asarray(solve(put(h)).dist)
     assert d[0, 3] == pytest.approx(1.0)
 
 
-def test_solve_batch_rejects_nan_stack():
+@HOST_AND_DEVICE
+def test_solve_batch_rejects_nan_stack(put):
     hs = np.stack([graph(8, s) for s in range(3)])
     hs[1, 4, 5] = np.nan
     with pytest.raises(InputValidationError, match=r"\(1, 4, 5\)"):
-        solve_batch(hs)
+        solve_batch(put(hs))
 
 
-def test_solve_batch_negative_cycle_in_one_graph():
-    hs = [graph(8, s) for s in range(3)]
+@pytest.mark.parametrize("bucket_by_size", [False, True],
+                         ids=["stacked", "bucketed"])
+@HOST_AND_DEVICE
+def test_solve_batch_negative_cycle_in_one_graph(put, bucket_by_size):
+    hs = [graph(n, s) for s, n in enumerate([8, 12, 20])]   # ragged
     hs[2][1, 2], hs[2][2, 1] = -5.0, 2.0
-    with pytest.raises(NegativeCycleError):
-        solve_batch(hs)
-    r = solve_batch(hs, validate=False)
+    hs = [put(m) for m in hs]
+    with pytest.raises(NegativeCycleError, match=r"entry \(2, 1\)"):
+        solve_batch(hs, bucket_by_size=bucket_by_size)
+    r = solve_batch(hs, bucket_by_size=bucket_by_size, validate=False)
     assert np.asarray(r.dist).shape[0] == 3
+
+
+@HOST_AND_DEVICE
+def test_negative_cycle_check_ignores_padding_diagonal(put):
+    d = np.zeros((2, 4, 4), np.float32)
+    d[1, 3, 3] = -1.0                     # graph 1 holds 3 nodes: padding
+    check_negative_cycles(put(d), TROPICAL, sizes=np.array([4, 3]))
+    d[1, 2, 2] = -1.0                     # inside graph 1's true block
+    with pytest.raises(NegativeCycleError, match=r"entry \(1, 2\)"):
+        check_negative_cycles(put(d), TROPICAL, sizes=np.array([4, 3]))
+
+
+def _nan_at_2_3(put):
+    h = graph()
+    h[2, 3] = np.nan
+    solve(put(h))
+
+
+def _stack_nan_at_1_4_5(put):
+    hs = np.stack([graph(8, s) for s in range(3)])
+    hs[1, 4, 5] = np.nan
+    solve_batch(put(hs))
+
+
+def _ragged_negative_cycle(put):
+    hs = [graph(n, s) for s, n in enumerate([8, 12, 20])]
+    hs[2][1, 2], hs[2][2, 1] = -5.0, 2.0
+    solve_batch([put(m) for m in hs], bucket_by_size=True)
+
+
+def _bf16_negative_cycle(put):
+    h = graph(8)
+    h[1, 2], h[2, 1] = -5.0, 2.0
+    solve(put(h), dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("case", [_nan_at_2_3, _stack_nan_at_1_4_5,
+                                  _ragged_negative_cycle, _bf16_negative_cycle],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_device_input_raises_what_host_input_raises(case):
+    """The device-side checks name the same entry in the same words."""
+    raised = []
+    for put in (np.asarray, jnp.asarray):
+        with pytest.raises((InputValidationError, NegativeCycleError)) as e:
+            case(put)
+        raised.append((type(e.value), str(e.value)))
+    assert raised[0] == raised[1]
 
 
 def test_dynamic_ctor_validates():
