@@ -12,7 +12,11 @@ Phases, all in this one process (no child process touches the chip):
      bit-exact against ``scipy.sparse.csgraph.dijkstra`` on the host.
   b  the same solve with ``with_pred=True`` at n=8192: the predecessor
      trees pass ``paths.validate_tree``, sampled rows match Dijkstra, and
-     reconstructed paths cost what ``dist`` says.
+     reconstructed paths cost what ``dist`` says.  The round is one Pallas
+     kernel (``fw_round_pred``); the benchmark's ``road8k.routes`` cell
+     reads 0.59-0.62 s a solve at this n on a TPU v5 lite (a new reading,
+     compile excluded), where the earlier composed argmin round took
+     55.5 s in this phase, compile included.
   c  ``launch.serve.serve_apsp``: batched ``solve_batch`` over ragged
      graphs, n_max=128, batch 256; every graph of the last batch bit-exact
      against Dijkstra.

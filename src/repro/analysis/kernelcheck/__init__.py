@@ -47,6 +47,7 @@ from .verify import Problem, check_call, verify_case
 from .lattice import (
     Case,
     case_for_fw_round_params,
+    case_for_fw_round_pred_params,
     case_for_minplus_params,
     case_for_row_close_params,
     default_cases,
@@ -65,6 +66,7 @@ __all__ = [
     "default_cases",
     "case_for_minplus_params",
     "case_for_fw_round_params",
+    "case_for_fw_round_pred_params",
     "case_for_row_close_params",
     "Mutant",
     "control_case",

@@ -44,6 +44,7 @@ __all__ = [
     "default_cases",
     "case_for_minplus_params",
     "case_for_fw_round_params",
+    "case_for_fw_round_pred_params",
     "case_for_row_close_params",
 ]
 
@@ -241,6 +242,64 @@ def case_for_fw_round_params(
     )
 
 
+def _fw_round_pred_oracle(d, p, o: int, b: int, sr: Semiring):
+    """The predecessor round from the ref oracles, witness for witness with
+    the kernel: ties to the smallest k, strict improvement over the tile;
+    an improving candidate takes the pivot row's P, or col′'s where k and j
+    are the same node; col′'s own witnesses always come from A*."""
+    dd = d if d.ndim == 3 else d[None]
+    pp = p if p.ndim == 3 else p[None]
+    n = dd.shape[-1]
+    cols = jnp.arange(n)[None, :]
+    outs, pouts = [], []
+    for gi in range(dd.shape[0]):
+        D, P = dd[gi], pp[gi]
+        piv, ppiv = fw_block_pred_ref(D[o:o + b, o:o + b],
+                                      P[o:o + b, o:o + b], sr)
+        stripes, pstripes = [], []
+        for i0 in range(0, n, b):
+            colp, kc = minplus_argmin_ref(D[i0:i0 + b, o:o + b], piv, sr)
+            pcolp = jnp.where(
+                kc < 0, -1, ppiv[jnp.maximum(kc, 0), jnp.arange(b)[None, :]])
+            z, ks = minplus_acc_argmin_ref(D[i0:i0 + b, :], colp,
+                                           D[o:o + b, :], sr)
+            k0 = jnp.maximum(ks, 0)
+            via = jnp.where(o + k0 == cols,
+                            jnp.take_along_axis(pcolp, k0, axis=1),
+                            P[o + k0, cols])
+            stripes.append(z)
+            pstripes.append(jnp.where(ks < 0, P[i0:i0 + b, :], via))
+        outs.append(jnp.concatenate(stripes, axis=0))
+        pouts.append(jnp.concatenate(pstripes, axis=0))
+    out, pout = jnp.stack(outs), jnp.stack(pouts)
+    return (out, pout) if d.ndim == 3 else (out[0], pout[0])
+
+
+def case_for_fw_round_pred_params(
+    block_size: int, n: int, *, o: Optional[int] = None, g: int = 0,
+    seed: int = 0, sr: Semiring = TROPICAL,
+) -> Case:
+    """Verification case for the predecessor round at one block size, with
+    the solver's initial predecessors (pred[i, j] = i on an arc, -1 off)."""
+    assert n % block_size == 0, (n, block_size)
+    b = block_size
+    oo = (n - b) if o is None else o
+    rng = np.random.default_rng(seed)
+    d = _dist(rng, (g, n, n) if g else (n, n), sr)
+    src = jnp.broadcast_to(jnp.arange(n)[:, None], d.shape)
+    p = jnp.where(sr.is_zero(d), -1, src).astype(jnp.int32)
+
+    def run(fn):
+        return fn(d, p, jnp.int32(oo), block_size=b, interpret=False,
+                  semiring=sr)
+
+    return Case(
+        name=f"fw_round_pred/b{b}@n{n}o{oo}g{g}",
+        module="fw_round", builder="fw_round_pred_pallas",
+        run=run, expected=lambda: _fw_round_pred_oracle(d, p, oo, b, sr),
+    )
+
+
 # ---------------------------------------------------------------------------
 # row_close family (scalar-prefetch gather)
 # ---------------------------------------------------------------------------
@@ -317,6 +376,10 @@ def default_cases() -> List[Case]:
         case_for_fw_round_params(8, 16, o=0, seed=12),
         case_for_fw_round_params(8, 16, g=2, seed=13),
         case_for_fw_round_params(64, 384, o=128, g=2, seed=17),
+        # -- fw_round_pred: the same points with the witness tiles --
+        case_for_fw_round_pred_params(8, 16, o=0, seed=18),
+        case_for_fw_round_pred_params(8, 16, g=2, seed=19),
+        case_for_fw_round_pred_params(64, 384, o=128, g=2, seed=20),
         # -- row_close: gather incl. row n-1 + duplicates, track, unaligned --
         case_for_row_close_params(dict(bn=128, bk=8, kc=8), 4, 16, seed=14),
         case_for_row_close_params(dict(bn=128, bk=8, kc=8), 4, 16, track=True,

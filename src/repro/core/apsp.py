@@ -154,6 +154,11 @@ def _device_bytes(a) -> int:
     return a.nbytes if _on_device(a) else 0
 
 
+def _pred_bytes(pred) -> int:
+    """Bytes of int32 predecessor state a solver made on the device."""
+    return 0 if pred is None else pred.nbytes
+
+
 def _validate_counts(hs) -> Dict[str, int]:
     """Span counts of :func:`validate_cost_matrix` over ``hs`` (one matrix
     or stack, or a list of them) that holds no NaN: each device input sends
@@ -325,7 +330,7 @@ def solve(
     if method not in METHODS:
         raise ValueError(f"unknown APSP method {method!r}; have {sorted(METHODS)}")
     sr = get_semiring(semiring)
-    with span(SOLVE, n=np.shape(h)[-1]):
+    with span(SOLVE, n=np.shape(h)[-1], with_pred=int(with_pred)):
         if validate:
             with span(VALIDATE, **_validate_counts(h)):
                 validate_cost_matrix(h, sr)
@@ -336,7 +341,8 @@ def solve(
                 donate = x is not h               # fresh copy -> safe to consume
             dist, pred = METHODS[method](x, with_pred, semiring=sr,
                                          donate=donate, **kwargs)
-            sp.set_metadata(bytes_to_device=0 if _on_device(h) else x.nbytes)
+            sp.set_metadata(bytes_to_device=0 if _on_device(h) else x.nbytes,
+                            pred_bytes=_pred_bytes(pred))
         if validate:
             with span(NEGCYCLE, **_negcycle_counts(dist, sr)):
                 check_negative_cycles(dist, sr)
@@ -485,10 +491,11 @@ def _solve_bucketed(
             pack.set_metadata(bytes_to_device=stack.nbytes)
             if dtype is not None:
                 stack = stack.astype(jnp.dtype(dtype))
-        with span(DISPATCH):
+        with span(DISPATCH) as sp:
             # pad_batch built a fresh stack -> safe to donate per bucket
             d, p = _solve_stack(stack, with_pred, method, semiring=semiring,
                                 donate=donate, **kwargs)
+            sp.set_metadata(pred_bytes=_pred_bytes(p))
         with span(FETCH, bytes_to_host=d.nbytes + (0 if p is None else p.nbytes)):
             d = np.asarray(d)
             p = None if p is None else np.asarray(p)
@@ -543,7 +550,7 @@ def solve_batch(
     if method not in METHODS:
         raise ValueError(f"unknown APSP method {method!r}; have {sorted(METHODS)}")
     semiring = get_semiring(semiring)
-    with span(SOLVE_BATCH, graphs=len(hs)) as call:
+    with span(SOLVE_BATCH, graphs=len(hs), with_pred=int(with_pred)) as call:
         if validate:
             with span(VALIDATE, graphs=len(hs), **_validate_counts(hs)):
                 if hasattr(hs, "ndim"):
@@ -599,9 +606,10 @@ def solve_batch(
                 stack = stack.astype(jnp.dtype(dtype))
         if donate is None:
             donate = stack is not hs          # fresh packed stack -> consume it
-        with span(DISPATCH):
+        with span(DISPATCH) as sp:
             dist, pred = _solve_stack(stack, with_pred, method,
                                       semiring=semiring, donate=donate, **kwargs)
+            sp.set_metadata(pred_bytes=_pred_bytes(pred))
         if validate:
             with span(NEGCYCLE, **_negcycle_counts(dist, semiring)):
                 check_negative_cycles(dist, semiring, sizes=sizes)

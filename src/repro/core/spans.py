@@ -10,10 +10,10 @@ added with ``set_metadata`` on the object ``span`` returns.
 
 import jax
 
-SOLVE = "apsp.solve"              # one solve call; counts n
-SOLVE_BATCH = "apsp.solve_batch"  # one solve_batch call; counts graphs, buckets
+SOLVE = "apsp.solve"              # one solve call; counts n, with_pred
+SOLVE_BATCH = "apsp.solve_batch"  # one solve_batch call; graphs, buckets, with_pred
 VALIDATE = "apsp.validate"        # NaN scan of the input; bytes_to_host, on_device
-DISPATCH = "apsp.dispatch"        # cast and solver enqueue
+DISPATCH = "apsp.dispatch"        # cast and solver enqueue; pred_bytes
 NEGCYCLE = "apsp.negcycle"        # solved-diagonal check; bytes_to_host, on_device
 PACK = "apsp.pack"                # one bucket's padded stack; work and bytes
 FETCH = "apsp.fetch"              # device results or inputs copied to the host

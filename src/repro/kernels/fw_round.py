@@ -33,10 +33,19 @@ is order-insensitive, so the two backends agree bit-for-bit (including
 bf16 mixed mode, which rounds at the same three points: closed pivot,
 col', output).
 
-The predecessor-tracking round is composed from the existing fused-argmin
-kernels in ``kernels.ops`` (it needs int32 witness state this kernel does
-not carry).  Scalar prefetch carries the pivot *tile index* so the solver
-can drive the round from inside a ``fori_loop`` with a traced offset.
+The predecessor-tracking round ``fw_round_pred_pallas`` is the same grid
+carrying int32 witness tiles beside the distance tiles: P in and out, the
+pivot row panel's P tile and the closed pivot's P (``fw_block_pred``), and
+a (B, B) int32 scratch for col′'s witnesses.  A candidate that strictly
+improves takes the row-side witness (``P[o+k, j]``), or the col-side one
+(``P′[i, k]``) where the row side is the diagonal's ``one`` (global k =
+global j): the rule of ``ops.pred_from_kstar``.  Only the tiles that hold
+pivot columns can meet that case, so only they pay the extra select.
+Distances are ``fw_round``'s bit for bit: the same candidate sums under
+the same ⊕.
+
+Scalar prefetch carries the pivot *tile index* so the solver can drive the
+round from inside a ``fori_loop`` with a traced offset.
 """
 
 from __future__ import annotations
@@ -50,10 +59,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.semiring import TROPICAL, Semiring
 
-from .fw_block import _window, close_tiles
-from .minplus import _fold
+from .fw_block import _closure_call, _window, close_tiles
+from .minplus import _band_rows, _fold, _row_group, _windows
 
-__all__ = ["fw_round_pallas", "PALLAS_BUILDERS"]
+__all__ = ["fw_round_pallas", "fw_round_pred_pallas", "PALLAS_BUILDERS"]
 
 
 def _col_tile(n: int) -> int:
@@ -137,7 +146,166 @@ def fw_round_pallas(
     return out if batched else out[0]
 
 
-# Raw (unjitted) builder for the kernel grid verifier — see
+def _fold_pred(x_ref, y_ref, px_ref, py_ref, z_ref, pz_ref, *, a_ref=None,
+               pa_ref=None, same=None, kc: int, sr: Semiring, cd):
+    """``z = a ⊕ x ⊗ y`` with witnesses, one register-sized row band at a
+    time (``a``/``pa`` default to ``z``/``pz`` themselves).
+
+    A candidate that strictly improves takes ``py[k, j]``; with ``same =
+    (col0, k0)`` (the global ids of z's column 0 and x's column 0) it takes
+    ``px[i, k]`` instead where k and j are the same node.
+    """
+    bm, bn = z_ref.shape
+    rows = _band_rows(bm, bn, True)
+    src = z_ref if a_ref is None else a_ref
+    psrc = pz_ref if pa_ref is None else pa_ref
+    grp = _row_group(y_ref.dtype, kc)
+    cols = None
+    if same is not None:
+        cols = same[0] + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+
+    def band(r, carry):
+        r0 = pl.multiple_of(r * rows, rows)
+
+        def window(k0, c):
+            acc, wit = c
+            xs = x_ref[pl.ds(r0, rows), pl.ds(k0, kc)].astype(cd)
+            pxs = None
+            if same is not None:
+                pxs = px_ref[pl.ds(r0, rows), pl.ds(k0, kc)]
+            for q in range(0, kc, grp):
+                ys = y_ref[pl.ds(k0 + q, grp), :].astype(cd)
+                pys = py_ref[pl.ds(k0 + q, grp), :]
+                for k in range(q, q + grp):
+                    cand = sr.mul(xs[:, k:k + 1], ys[k - q:k - q + 1, :])
+                    better = sr.better(cand, acc)
+                    acc = sr.add(acc, cand)
+                    w = pys[k - q:k - q + 1, :]
+                    if same is not None:
+                        w = jnp.where(cols == same[1] + k0 + k,
+                                      pxs[:, k:k + 1], w)
+                    wit = jnp.where(better, w, wit)
+            return acc, wit
+
+        init = (src[pl.ds(r0, rows), :].astype(cd), psrc[pl.ds(r0, rows), :])
+        acc, wit = _windows(x_ref.shape[-1], kc, window, init)
+        z_ref[pl.ds(r0, rows), :] = acc.astype(z_ref.dtype)
+        pz_ref[pl.ds(r0, rows), :] = wit
+        return carry
+
+    jax.lax.fori_loop(0, bm // rows, band, 0)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_size", "interpret", "semiring")
+)
+def fw_round_pred_pallas(
+    d: jax.Array,
+    p: jax.Array,
+    o: jax.Array,
+    *,
+    block_size: int,
+    interpret: bool = False,
+    semiring: Semiring = TROPICAL,
+):
+    """One fused blocked-FW round with predecessors, (N, N) or (G, N, N).
+
+    ``p`` holds global node ids (-1: no path).  Returns ``(d', p')``: the
+    pivot closure dispatch (``fw_block_pred``) plus one row-stripe grid,
+    ``fw_round_pallas``'s grid and tiles with the int32 witness tiles
+    beside the distance tiles.
+    """
+    sr = semiring
+    b = block_size
+    batched = d.ndim == 3
+    dd = d if batched else d[None]
+    pp = p if batched else p[None]
+    g, n, n2 = dd.shape
+    assert n == n2 and n % b == 0 and pp.shape == dd.shape, (d.shape, p.shape, b)
+    storage = d.dtype
+    cd = jnp.float32 if storage == jnp.bfloat16 else storage
+    bn = _col_tile(n)
+    lc = _window(b)
+
+    pivot, ppivot = _closure_call(
+        jax.lax.dynamic_slice(dd, (0, o, o), (g, b, b)).astype(cd),
+        jax.lax.dynamic_slice(pp, (0, o, o), (g, b, b)),
+        interpret=interpret, semiring=sr,
+    )
+    pivot = pivot.astype(storage)
+    colpan = jax.lax.dynamic_slice(dd, (0, 0, o), (g, n, b))
+
+    def kern(t_ref, piv_ref, ppiv_ref, col_ref, row_ref, prow_ref, acc_ref,
+             pacc_ref, o_ref, po_ref, colp_ref, pcolp_ref):
+        j = pl.program_id(2)
+        off = t_ref[0] * b                      # global id of pivot column 0
+
+        @pl.when(j == 0)
+        def _col_panel():
+            # col' = col ⊗ A*.  Its k == c candidate (A*'s diagonal one)
+            # takes A*'s witness, not col's: no round reads it, since the
+            # stripe fold below improves on tile = col only where col'
+            # beats col, i.e. where some k != c won.
+            colp_ref[...] = jnp.full(colp_ref.shape, sr.zero, cd)
+            pcolp_ref[...] = jnp.full(pcolp_ref.shape, -1, jnp.int32)
+            _fold_pred(col_ref, piv_ref, None, ppiv_ref, colp_ref, pcolp_ref,
+                       kc=lc, sr=sr, cd=cd)
+            if storage != cd:                  # round col' like fw_round
+                colp_ref[...] = colp_ref[...].astype(storage).astype(cd)
+
+        lo = j * bn
+        pivot_cols = jnp.logical_and(lo < off + b, off < lo + bn)
+
+        @pl.when(pivot_cols)
+        def _with_pivot_cols():
+            _fold_pred(colp_ref, row_ref, pcolp_ref, prow_ref, o_ref, po_ref,
+                       a_ref=acc_ref, pa_ref=pacc_ref, same=(lo, off), kc=lc,
+                       sr=sr, cd=cd)
+
+        @pl.when(jnp.logical_not(pivot_cols))
+        def _without():
+            _fold_pred(colp_ref, row_ref, pcolp_ref, prow_ref, o_ref, po_ref,
+                       a_ref=acc_ref, pa_ref=pacc_ref, kc=lc, sr=sr, cd=cd)
+
+    tile = lambda gi, i, j, t: (gi, i, j)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(g, n // b, n // bn),
+        in_specs=[
+            pl.BlockSpec((None, b, b), lambda gi, i, j, t: (gi, 0, 0)),
+            pl.BlockSpec((None, b, b), lambda gi, i, j, t: (gi, 0, 0)),
+            pl.BlockSpec((None, b, b), lambda gi, i, j, t: (gi, i, 0)),
+            pl.BlockSpec((None, b, bn), lambda gi, i, j, t: (gi, t[0], j)),
+            pl.BlockSpec((None, b, bn), lambda gi, i, j, t: (gi, t[0], j)),
+            pl.BlockSpec((None, b, bn), tile),
+            pl.BlockSpec((None, b, bn), tile),
+        ],
+        out_specs=[pl.BlockSpec((None, b, bn), tile),
+                   pl.BlockSpec((None, b, bn), tile)],
+        scratch_shapes=[pltpu.VMEM((b, b), cd), pltpu.VMEM((b, b), jnp.int32)],
+    )
+    params = {}
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        )
+    t = jnp.reshape(o // b, (1,)).astype(jnp.int32)
+    out, pout = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((g, n, n), storage),
+                   jax.ShapeDtypeStruct((g, n, n), jnp.int32)],
+        interpret=interpret,
+        name="fw_round_pred",
+        **params,
+    )(t, pivot, ppivot, colpan, dd, pp, dd, pp)
+    return (out, pout) if batched else (out[0], pout[0])
+
+
+# Raw (unjitted) builders for the kernel grid verifier — see
 # ``repro.analysis.kernelcheck`` and the authoring checklist in
 # COMPAT.md §Static analysis.
-PALLAS_BUILDERS = {"fw_round_pallas": fw_round_pallas.__wrapped__}
+PALLAS_BUILDERS = {
+    "fw_round_pallas": fw_round_pallas.__wrapped__,
+    "fw_round_pred_pallas": fw_round_pred_pallas.__wrapped__,
+}

@@ -501,17 +501,29 @@ def fw_round_pred(
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused multi-stage round with predecessor propagation.
 
-    Same three stages as :func:`fw_round`, composed from the fused-argmin
+    Same three stages as :func:`fw_round`.  On the pallas/interpret
+    backends they run as the pivot closure plus one Pallas grid that
+    carries the int32 witness tiles (``kernels.fw_round``).  The XLA
+    fallback, the kernel's reference, composes them from the fused-argmin
     primitives (the witness state k* rides each stage): pivot closure via
     :func:`fw_block_pred`, col' via one accumulate :func:`minplus_pred`,
     and the full update via one accumulate :func:`minplus_pred` — the
     stripe/pivot subsumption argument carries over because the pred rule
     only reads the winning k*.  Values are identical to :func:`fw_round`
     (the col' accumulate's ``col ⊕ .`` candidates are already inside the
-    plain product's candidate set: A* carries ``one`` on its diagonal).
+    plain product's candidate set: A* carries ``one`` on its diagonal);
+    the two backends may break ties between equal paths differently.
     """
     sr = get_semiring(semiring)
     _check_mixed(sr, d)
+    b = backend()
+    if b != "xla":
+        from .fw_round import fw_round_pred_pallas
+
+        return fw_round_pred_pallas(
+            d, p, o, block_size=block_size, interpret=(b == "interpret"),
+            semiring=sr,
+        )
     bsz = block_size
     n = d.shape[-1]
     if d.ndim == 3:

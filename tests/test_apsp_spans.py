@@ -61,10 +61,11 @@ def test_solve_spans_and_counts(tmp_path):
         (S.SOLVE, None), (S.VALIDATE, S.SOLVE), (S.DISPATCH, S.SOLVE),
         (S.NEGCYCLE, S.SOLVE)]
     counts = {s[2]: s[3] for s in got}
-    assert counts[S.SOLVE] == {"n": n}
+    assert counts[S.SOLVE] == {"n": n, "with_pred": 0}
     # the NaN scan runs on the device: its one-byte verdict crosses
     assert counts[S.VALIDATE] == {"bytes_to_host": 1, "on_device": 1}
-    assert counts[S.DISPATCH] == {"bytes_to_device": 0}     # already on device
+    # already on device; no predecessor state asked for
+    assert counts[S.DISPATCH] == {"bytes_to_device": 0, "pred_bytes": 0}
     # the negative-cycle check reads the solved diagonal alone
     assert counts[S.NEGCYCLE] == {"bytes_to_host": n * 4, "on_device": 1}
     moved = sum(c.get("bytes_to_host", 0) for c in counts.values())
@@ -76,7 +77,7 @@ def test_solve_host_input_counts_its_upload(tmp_path):
     got = _traced(tmp_path, lambda: solve(h))
     counts = {s[2]: s[3] for s in got}
     assert counts[S.VALIDATE] == {"bytes_to_host": 0, "on_device": 0}
-    assert counts[S.DISPATCH] == {"bytes_to_device": 12 * 12 * 4}
+    assert counts[S.DISPATCH] == {"bytes_to_device": 12 * 12 * 4, "pred_bytes": 0}
     assert counts[S.NEGCYCLE] == {"bytes_to_host": 12 * 4, "on_device": 1}
 
 
@@ -90,7 +91,8 @@ def test_bucketed_solve_batch_spans_and_counts(tmp_path):
         [S.SOLVE_BATCH, S.VALIDATE, S.FETCH] + per_bucket * 3
         + [S.NEGCYCLE, S.SCATTER])
     assert all(s[4] == S.SOLVE_BATCH for s in got[1:])
-    assert got[0][3] == {"graphs": 5, "buckets": 3}
+    assert got[0][3] == {"graphs": 5, "buckets": 3, "with_pred": 0}
+    assert [s[3] for s in got if s[2] == S.DISPATCH] == [{"pred_bytes": 0}] * 3
     assert got[1][3] == {"graphs": 5, "bytes_to_host": 0, "on_device": 0}
     packs = [s[3] for s in got if s[2] == S.PACK]
     assert [(p["edge"], p["graphs"], p["slots"]) for p in packs] == [
@@ -116,7 +118,7 @@ def test_stacked_solve_batch_spans_and_counts(tmp_path):
         (S.SOLVE_BATCH, None), (S.VALIDATE, S.SOLVE_BATCH),
         (S.PACK, S.SOLVE_BATCH), (S.DISPATCH, S.SOLVE_BATCH),
         (S.NEGCYCLE, S.SOLVE_BATCH)]
-    assert got[0][3] == {"graphs": 3, "buckets": 1}
+    assert got[0][3] == {"graphs": 3, "buckets": 1, "with_pred": 0}
     assert got[1][3] == {"graphs": 3, "bytes_to_host": 1, "on_device": 1}
     # a ragged device stack is read on the host and packed again
     assert got[2][3] == {"edge": 20, "graphs": 3, "slots": 3,
@@ -125,6 +127,24 @@ def test_stacked_solve_batch_spans_and_counts(tmp_path):
                          "bytes_to_host": stack.nbytes,
                          "bytes_to_device": stack.nbytes}
     assert got[4][3] == {"bytes_to_host": 3 * 20 * 4, "on_device": 1}
+
+
+def test_pred_counts(tmp_path):
+    """A route solve counts its int32 predecessor state: the whole (n, n)
+    table for ``solve``, each bucket's padded stack for ``solve_batch``."""
+    n = 16
+    h = jnp.asarray(_ragged([n])[0])
+    got = _traced(tmp_path / "one",
+                  lambda: solve(h, with_pred=True).pred.block_until_ready())
+    counts = {s[2]: s[3] for s in got}
+    assert counts[S.SOLVE] == {"n": n, "with_pred": 1}
+    assert counts[S.DISPATCH] == {"bytes_to_device": 0, "pred_bytes": n * n * 4}
+    sizes = [5, 12, 9]               # buckets: edge 8 in 1 slot, 16 in 2
+    got = _traced(tmp_path / "batch", lambda: solve_batch(
+        _ragged(sizes), with_pred=True, bucket_by_size=True))
+    assert got[0][3] == {"graphs": 3, "buckets": 2, "with_pred": 1}
+    assert [s[3]["pred_bytes"] for s in got if s[2] == S.DISPATCH] == [
+        4 * 8 * 8, 4 * 2 * 16 * 16]
 
 
 @pytest.mark.parametrize("bucket_by_size", [True, False])

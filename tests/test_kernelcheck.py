@@ -59,10 +59,11 @@ def test_default_lattice_spans_the_claimed_shapes():
     names = " ".join(c.name for c in _DEFAULT)
     # at least one of each claimed lattice point: aligned, padded, batched,
     # fused accumulate, witness tracking, non-tropical semirings, the
-    # in-place round, and the scalar-prefetch row gather
+    # in-place round with and without predecessors, and the scalar-prefetch
+    # row gather
     for tag in ("aligned", "padded", "batched", "accumulate", "argmin",
                 "bottleneck", "reliability", "fw_block", "fw_round",
-                "row_close"):
+                "fw_round_pred", "row_close"):
         assert tag in names, f"lattice lost its {tag} coverage"
 
 

@@ -74,7 +74,7 @@ def _kernel_names(hlo: str) -> set:
 
 def _kernels():
     from repro.kernels.fw_block import fw_block_pred_pallas
-    from repro.kernels.fw_round import fw_round_pallas
+    from repro.kernels.fw_round import fw_round_pallas, fw_round_pred_pallas
     from repro.kernels.minplus import minplus_argmin_pallas, minplus_pallas
     from repro.kernels.row_close import row_close_pallas
 
@@ -107,6 +107,12 @@ def _kernels():
         "fw_round_batched": (
             lambda d, o: fw_round_pallas(d, o, block_size=128),
             [((256, 128, 128), f32), ((), i32)]),
+        "fw_round_pred": (
+            lambda d, p, o: fw_round_pred_pallas(d, p, o, block_size=BLOCK),
+            [((N, N), f32), ((N, N), i32), ((), i32)]),
+        "fw_round_pred_batched": (
+            lambda d, p, o: fw_round_pred_pallas(d, p, o, block_size=128),
+            [((256, 128, 128), f32), ((256, 128, 128), i32), ((), i32)]),
         "fw_block_pred": (
             lambda d, p: fw_block_pred_pallas(d, p),
             [((BLOCK, BLOCK), f32), ((BLOCK, BLOCK), i32)]),
@@ -150,6 +156,17 @@ def test_blocked_solve_names_its_kernels(one_chip, no_compile_cache,
     hlo = _compile(lambda d: blocked_fw(d, block_size=BLOCK,
                                         round_mode="fused")[0], x)
     assert _kernel_names(hlo) == {"fw_block", "fw_round"}
+
+
+def test_blocked_route_solve_names_its_kernels(one_chip, no_compile_cache,
+                                               monkeypatch):
+    # a solve with predecessors runs the predecessor round's own two kernels
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    from repro.core.blocked_fw import blocked_fw
+
+    x = jax.ShapeDtypeStruct((2048, 2048), jnp.float32, sharding=one_chip)
+    hlo = _compile(lambda d: blocked_fw(d, block_size=BLOCK, with_pred=True), x)
+    assert _kernel_names(hlo) == {"fw_block_pred", "fw_round_pred"}
 
 
 def test_minplus_variants_have_distinct_names(one_chip, no_compile_cache):
