@@ -41,6 +41,7 @@ in ``launch/serve.py --arch apsp``.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -115,7 +116,8 @@ def validate_cost_matrix(h, semiring: SemiringLike = "tropical") -> None:
 
 
 def check_negative_cycles(
-    dist, semiring: Semiring, sizes: Optional[np.ndarray] = None
+    dist, semiring: Semiring, sizes: Optional[np.ndarray] = None,
+    diagonal: Optional[jax.Array] = None,
 ) -> None:
     """Tropical-only post-solve contract check: a strictly negative entry on
     the *solved* diagonal means the graph contains a negative cycle, so
@@ -127,11 +129,14 @@ def check_negative_cycles(
     (G, n, n); ``sizes`` restricts each graph's check to its true block
     (padding diagonals are the semiring one by construction).  Only the
     diagonal is read: a device ``dist`` has it gathered on the device and
-    copies those n (or G·n) entries to the host, nothing else."""
+    copies those n (or G·n) entries to the host, nothing else; a caller
+    whose program already gathered it passes it as ``diagonal``."""
     if semiring.name != "tropical":
         return
-    diag = np.asarray(_diagonal(dist) if _on_device(dist)
-                      else np.diagonal(dist, axis1=-2, axis2=-1))
+    if diagonal is None:
+        diagonal = (_diagonal(dist) if _on_device(dist)
+                    else np.diagonal(dist, axis1=-2, axis2=-1))
+    diag = np.asarray(diagonal)
     neg = diag < 0
     if sizes is not None:
         neg = neg & (np.arange(diag.shape[-1]) < np.asarray(sizes)[:, None])
@@ -456,56 +461,94 @@ def _group_by_edge(sizes: np.ndarray) -> Dict[int, List[int]]:
     return buckets
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "slots", "edge", "dtype", "semiring", "with_pred"))
+def _staging(slots: int, edge: int, dtype, semiring: Semiring, with_pred: bool):
+    """(slots, edge, edge) stacks of inert graphs, the semiring's zero off
+    the diagonal and its one on it; with ``with_pred`` also the int32
+    predecessor stack (-1, identity diagonal)."""
+    dist = jnp.broadcast_to(semiring.eye(edge, dtype), (slots, edge, edge))
+    if not with_pred:
+        return (dist,)
+    idx = jnp.arange(edge, dtype=jnp.int32)
+    pred = jnp.where(idx[:, None] == idx[None, :], idx[None, :], -1)
+    return dist, jnp.broadcast_to(pred, (slots, edge, edge))
+
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnames=(
+    "with_pred", "method", "solvers", "semiring", "dtype", "solver_kw"))
+def _solve_into(staging, stack, offset, *, with_pred, method, solvers,
+                semiring, dtype, solver_kw):
+    """Solve one bucket's (slots, edge, edge) stack in ``dtype`` and write
+    its results into rows ``offset:offset + slots`` of the staging stacks,
+    in place.  The staging's edge is at least the bucket's and the rest of
+    each row is inert, so a row reads as the graph solved at the larger
+    edge.  One program per bucket shape and staging shape, as the solver
+    alone.  ``solvers``, the registry's entries for ``method``, are part
+    of the program's key: a method registered anew is traced anew.  What
+    a solver decides on the host (the autotune table's block size) is
+    fixed when its shape is first traced."""
+    out = _solve_stack(stack.astype(dtype), with_pred, method,
+                       semiring=semiring, **dict(solver_kw))
+    return tuple(jax.lax.dynamic_update_slice(s, r.astype(s.dtype),
+                                              (offset, 0, 0))
+                 for s, r in zip(staging, out))
+
+
+@functools.partial(jax.jit, static_argnames="n")
+def _gather_frame(staging, rows, n: int):
+    """The (G, n, n) frame, graph i from staging row ``rows[i]``, and the
+    distance frame's diagonal (G, n) for the negative-cycle check."""
+    frames = tuple(jnp.take(s, rows, axis=0, mode="clip")[:, :n, :n]
+                   for s in staging)
+    return frames, jnp.diagonal(frames[0], axis1=-2, axis2=-1)
+
+
 def _solve_bucketed(
     mats: List[np.ndarray], sizes: np.ndarray, buckets: Dict[int, List[int]],
-    n: int, method: str, with_pred: bool, semiring=TROPICAL, donate=True,
-    dtype=None, **kwargs
-) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    n: int, method: str, with_pred: bool, semiring=TROPICAL, dtype=None,
+    **kwargs
+) -> Tuple[Tuple[jax.Array, ...], jax.Array]:
     """Size-bucketed batched solve: graphs grouped by power-of-two padded
     edge (``buckets``, from :func:`_group_by_edge`), one batched program
-    per bucket, results scattered back into the common (G, n, n) frame,
-    which stays on the host for the caller to check and upload.
-    Bit-identical to the single-stack path — padding is inert either way —
-    but a ragged corpus does ~size^3 work per graph instead of n_max^3.
-    Per-bucket stacks are fresh, so they donate unless the caller opted
-    out; ``dtype`` casts each bucket's stack (bf16 mixed mode) and the
-    scattered result frame."""
+    per bucket.  Nothing comes back to the host: each bucket's program
+    writes its results into a staging stack on the device (bucket after
+    bucket, at the edge of the largest bucket), and one gather puts graph
+    i at index i of the common (G, n, n) frame.  So bucket k solves while
+    the host packs bucket k+1, and the compiled programs are keyed on the
+    bucket and staging shapes, not on the graphs' order.  Bit-identical
+    to the single-stack path — padding is inert either way — but a ragged
+    corpus does ~size^3 work per graph instead of n_max^3.  ``dtype``
+    casts each bucket's stack (bf16 mixed mode) and sets the frame's.
+    Returns the frames (dist, then pred with ``with_pred``) and the
+    distance frame's diagonal, still on the device."""
     g = len(mats)
-    out_dtype = np.float32 if dtype is None else jnp.dtype(dtype)
-    dist = np.full((g, n, n), semiring.zero, out_dtype)
-    idx = np.arange(n)
-    dist[:, idx, idx] = semiring.one
-    pred = None
-    if with_pred:
-        pred = np.full((g, n, n), -1, np.int32)
-        pred[:, idx, idx] = idx
-
+    dtype = jnp.dtype(jnp.float32 if dtype is None else dtype)
+    slots = {edge: _bucket_count(len(m)) for edge, m in buckets.items()}
+    staging = _staging(next_pow2(sum(slots.values())), _bucket_edge(n),
+                       dtype, semiring, with_pred)
+    solvers = (METHODS[method], BATCH_METHODS.get(method))
+    solver_kw = tuple(sorted(kwargs.items()))
+    rows = np.empty(g, np.int32)
+    offset = 0
     for edge, members in sorted(buckets.items()):
-        slots = _bucket_count(len(members))
         sub = [mats[i] for i in members]
-        sub += [np.zeros((0, 0), np.float32)] * (slots - len(members))
-        with span(PACK, edge=edge, graphs=len(members), slots=slots,
+        sub += [np.zeros((0, 0), np.float32)] * (slots[edge] - len(members))
+        with span(PACK, edge=edge, graphs=len(members), slots=slots[edge],
                   useful_work=int(np.sum(sizes[members] ** 3)),
-                  padded_work=slots * edge ** 3) as pack:
+                  padded_work=slots[edge] * edge ** 3) as pack:
             stack, _ = pad_batch(sub, n_max=edge, semiring=semiring)
             pack.set_metadata(bytes_to_device=stack.nbytes)
-            if dtype is not None:
-                stack = stack.astype(jnp.dtype(dtype))
-        with span(DISPATCH) as sp:
-            # pad_batch built a fresh stack -> safe to donate per bucket
-            d, p = _solve_stack(stack, with_pred, method, semiring=semiring,
-                                donate=donate, **kwargs)
-            sp.set_metadata(pred_bytes=_pred_bytes(p))
-        with span(FETCH, bytes_to_host=d.nbytes + (0 if p is None else p.nbytes)):
-            d = np.asarray(d)
-            p = None if p is None else np.asarray(p)
-        with span(SCATTER):
-            for j, i in enumerate(members):
-                k = int(sizes[i])
-                dist[i, :k, :k] = d[j, :k, :k]
-                if with_pred:
-                    pred[i, :k, :k] = p[j, :k, :k]
-    return dist, pred
+        with span(DISPATCH, pred_bytes=4 * stack.size if with_pred else 0):
+            staging = _solve_into(
+                staging, stack, offset, with_pred=with_pred, method=method,
+                solvers=solvers, semiring=semiring, dtype=dtype,
+                solver_kw=solver_kw)
+        rows[members] = offset + np.arange(len(members))
+        offset += slots[edge]
+    # the row index vector (G int32) is all that crosses
+    with span(SCATTER, bytes_to_device=rows.nbytes, on_device=1):
+        return _gather_frame(staging, rows, n)
 
 
 def solve_batch(
@@ -540,12 +583,15 @@ def solve_batch(
     ``donate``/``dtype`` follow :func:`solve`: None auto-donates the
     padded stack whenever packing made a fresh buffer (always, except a
     full-size pre-stacked jax input), halving the resident batch state for
-    the natively-batched in-place solvers; ``dtype=jnp.bfloat16`` selects
+    the natively-batched in-place solvers (the size-bucketed path's stacks
+    are its own and write into a staging stack, so ``donate`` does not
+    reach them); ``dtype=jnp.bfloat16`` selects
     mixed precision (tropical only).  ``validate`` follows :func:`solve`
     (NaN rejection per graph + tropical negative-cycle detection on each
-    unpadded diagonal; ``validate=False`` to skip on hot paths).  The
-    size-bucketed path checks the diagonals on its host frame before the
-    upload, so nothing is read back.
+    unpadded diagonal; ``validate=False`` to skip on hot paths).  Both
+    paths keep their results on the device: the negative-cycle check
+    copies the solved diagonals (G·N entries) to the host, and with
+    ``validate=False`` nothing is read back.
     """
     if method not in METHODS:
         raise ValueError(f"unknown APSP method {method!r}; have {sorted(METHODS)}")
@@ -576,17 +622,15 @@ def solve_batch(
                 raise ValueError(f"n_max={n} smaller than largest graph")
             buckets = _group_by_edge(sizes_)
             call.set_metadata(buckets=len(buckets))
-            dist, pred = _solve_bucketed(
+            frames, diag = _solve_bucketed(
                 mats, sizes_, buckets, n, method, with_pred, semiring=semiring,
-                donate=donate is not False, dtype=dtype, **kwargs
+                dtype=dtype, **kwargs
             )
-            if validate:       # on the host frame, before its upload
+            dist, pred = frames if with_pred else (frames[0], None)
+            if validate:
                 with span(NEGCYCLE, **_negcycle_counts(dist, semiring)):
-                    check_negative_cycles(dist, semiring, sizes=sizes_)
-            upload = dist.nbytes + (0 if pred is None else pred.nbytes)
-            with span(SCATTER, bytes_to_device=upload):
-                dist = jnp.asarray(dist)
-                pred = None if pred is None else jnp.asarray(pred)
+                    check_negative_cycles(dist, semiring, sizes=sizes_,
+                                          diagonal=diag)
             return BatchAPSPResult(dist=dist, pred=pred, sizes=sizes_,
                                    method=method)
         call.set_metadata(buckets=1)

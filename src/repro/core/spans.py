@@ -16,8 +16,8 @@ VALIDATE = "apsp.validate"        # NaN scan of the input; bytes_to_host, on_dev
 DISPATCH = "apsp.dispatch"        # cast and solver enqueue; pred_bytes
 NEGCYCLE = "apsp.negcycle"        # solved-diagonal check; bytes_to_host, on_device
 PACK = "apsp.pack"                # one bucket's padded stack; work and bytes
-FETCH = "apsp.fetch"              # device results or inputs copied to the host
-SCATTER = "apsp.scatter"          # results copied into the frame, frame upload
+FETCH = "apsp.fetch"              # inputs read as host arrays
+SCATTER = "apsp.scatter"          # the batch frame gathered on the device
 
 
 def span(name: str, **counts: int) -> jax.profiler.TraceAnnotation:

@@ -85,11 +85,13 @@ def test_bucketed_solve_batch_spans_and_counts(tmp_path):
     sizes = [5, 12, 20, 9, 30]
     # buckets: edge 8 {5} in 1 slot, 16 {12, 9} in 2, 32 {20, 30} in 2
     mats = _ragged(sizes)
-    got = _traced(tmp_path, lambda: solve_batch(mats, bucket_by_size=True))
-    per_bucket = [S.PACK, S.DISPATCH, S.FETCH, S.SCATTER]
+    got = _traced(tmp_path / "checked",
+                  lambda: solve_batch(mats, bucket_by_size=True))
+    # each bucket's program writes its results on the device: no read-back
+    per_bucket = [S.PACK, S.DISPATCH]
     assert [s[2] for s in got] == (
         [S.SOLVE_BATCH, S.VALIDATE, S.FETCH] + per_bucket * 3
-        + [S.NEGCYCLE, S.SCATTER])
+        + [S.SCATTER, S.NEGCYCLE])
     assert all(s[4] == S.SOLVE_BATCH for s in got[1:])
     assert got[0][3] == {"graphs": 5, "buckets": 3, "with_pred": 0}
     assert [s[3] for s in got if s[2] == S.DISPATCH] == [{"pred_bytes": 0}] * 3
@@ -101,11 +103,18 @@ def test_bucketed_solve_batch_spans_and_counts(tmp_path):
     assert [p["padded_work"] for p in packs] == [8 ** 3, 2 * 16 ** 3, 2 * 32 ** 3]
     assert [p["bytes_to_device"] for p in packs] == [
         4 * 8 * 8, 4 * 2 * 16 * 16, 4 * 2 * 32 * 32]
-    fetches = [s[3]["bytes_to_host"] for s in got if s[2] == S.FETCH]
-    assert fetches == [0, 4 * 8 * 8, 4 * 2 * 16 * 16, 4 * 2 * 32 * 32]
-    # the host frame is checked before its upload: nothing is read back
-    assert got[-2][3] == {"bytes_to_host": 0, "on_device": 0}
-    assert got[-1][3] == {"bytes_to_device": 5 * 30 * 30 * 4}
+    assert got[2][3] == {"bytes_to_host": 0}          # host inputs, as given
+    # the frame is gathered on the device; only its row indices go up
+    assert got[-2][3] == {"bytes_to_device": 5 * 4, "on_device": 1}
+    # the solved diagonals are all that crosses to the host
+    assert got[-1][3] == {"bytes_to_host": 5 * 30 * 4, "on_device": 1}
+    assert sum(s[3].get("bytes_to_host", 0) for s in got) == 5 * 30 * 4
+    # unchecked, nothing at all is read back
+    got = _traced(tmp_path / "unchecked", lambda: solve_batch(
+        mats, bucket_by_size=True, validate=False))
+    assert [s[2] for s in got] == (
+        [S.SOLVE_BATCH, S.FETCH] + per_bucket * 3 + [S.SCATTER])
+    assert sum(s[3].get("bytes_to_host", 0) for s in got) == 0
 
 
 def test_stacked_solve_batch_spans_and_counts(tmp_path):

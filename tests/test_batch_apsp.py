@@ -1,10 +1,12 @@
 """solve_batch vs per-graph solve: exact equivalence on ragged batches."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from conftest import np_floyd_warshall
+from oracle import generate
 from repro.core import (
     generate_batch,
     generate_np,
@@ -73,6 +75,55 @@ def test_bucketed_equals_single_stack(method, ragged_graphs):
     assert np.array_equal(np.asarray(a.dist), np.asarray(b.dist))
     assert np.array_equal(np.asarray(a.pred), np.asarray(b.pred))
     assert np.array_equal(a.sizes, b.sizes)
+
+
+# the edge-32 bucket holds three graphs in four slots
+FRAME_SIZES = [4, 17, 33, 9, 30, 12, 20]
+
+
+@pytest.mark.parametrize("semiring,dtype,sizes,n_max", [
+    ("tropical", None, FRAME_SIZES, None),
+    ("bottleneck", None, FRAME_SIZES, None),
+    ("tropical", jnp.bfloat16, FRAME_SIZES, None),
+    ("tropical", None, [33, 5], None),        # bucket edge 64 > n = 33
+    ("tropical", None, [6, 11, 19], 40),      # n_max above the largest graph
+], ids=["tropical", "bottleneck", "bf16", "edge-past-n", "n_max-past-graphs"])
+def test_bucketed_frame_bit_identical(semiring, dtype, sizes, n_max):
+    """The bucketed frame, built and filled on the device, equals the
+    single-stack result bit for bit, padding included."""
+    rng = np.random.default_rng(11)
+    hs = [generate(rng, k, semiring) for k in sizes]
+    kw = dict(method="blocked_fw", block_size=8, with_pred=True,
+              semiring=semiring, dtype=dtype, n_max=n_max)
+    a = solve_batch(hs, **kw)
+    b = solve_batch(hs, bucket_by_size=True, **kw)
+    for r in (a, b):
+        assert isinstance(r.dist, jax.Array) and isinstance(r.pred, jax.Array)
+    n = max(sizes) if n_max is None else n_max
+    assert b.dist.shape == b.pred.shape == (len(sizes), n, n)
+    assert (b.dist.dtype, b.pred.dtype) == (a.dist.dtype, a.pred.dtype)
+    assert np.array_equal(np.asarray(a.dist, np.float32),
+                          np.asarray(b.dist, np.float32))
+    assert np.array_equal(np.asarray(a.pred), np.asarray(b.pred))
+
+
+def test_bucketed_follows_the_registry(monkeypatch):
+    """A batched solver registered after a bucketed call of the same shapes
+    is the one the next call runs: compiled bucket programs are keyed on
+    the registry's entries, not on the method's name."""
+    from repro.core import apsp
+
+    hs = [generate(np.random.default_rng(5), k, "tropical") for k in (6, 11)]
+    before = np.asarray(solve_batch(hs, bucket_by_size=True).dist)
+    orig = apsp.BATCH_METHODS["blocked_fw"]
+
+    def shifted(stack, with_pred, **kw):
+        d, p = orig(stack, with_pred, **kw)
+        return d + 1.0, p
+
+    monkeypatch.setitem(apsp.BATCH_METHODS, "blocked_fw", shifted)
+    after = np.asarray(solve_batch(hs, bucket_by_size=True).dist)
+    assert np.array_equal(after[0, :6, :6], before[0, :6, :6] + 1.0)
 
 
 def test_batch_matches_numpy_oracle(ragged_graphs):

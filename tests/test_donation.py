@@ -85,6 +85,43 @@ def test_solve_batch_donation(rng):
         assert np.isfinite(m).any() and m.shape == (r0.sizes[i],) * 2
 
 
+@pytest.mark.parametrize("with_pred", [False, True])
+def test_bucket_results_written_into_staging_in_place(with_pred):
+    """Each bucket's program aliases the donated staging stacks to its
+    outputs and consumes them; its rows land at the offset, the rest of
+    the staging stays inert."""
+    from repro.analysis.donation import parse_input_output_alias
+    from repro.core.apsp import (BATCH_METHODS, METHODS, _solve_into,
+                                 _staging)
+    from repro.core.semiring import TROPICAL
+
+    def staging():
+        return _staging(8, 16, jnp.dtype(jnp.float32), TROPICAL, with_pred)
+
+    h = np.full((2, 8, 8), np.inf, np.float32)
+    h[:, 0, 1] = 3.0
+    h[:, np.arange(8), np.arange(8)] = 0.0
+    kw = dict(with_pred=with_pred, method="blocked_fw",
+              solvers=(METHODS["blocked_fw"], BATCH_METHODS["blocked_fw"]),
+              semiring=TROPICAL, dtype=jnp.dtype(jnp.float32),
+              solver_kw=(("block_size", 8),))
+    hlo = _solve_into.lower(staging(), jnp.asarray(h), 5, **kw).compile()
+    assert set(parse_input_output_alias(hlo.as_text())) == set(
+        range(1 + with_pred))
+    before = staging()
+    out = _solve_into(before, jnp.asarray(h), 5, **kw)
+    assert all(_deleted(s) for s in before)
+    ref = solve_batch(h, method="blocked_fw", block_size=8,
+                      with_pred=with_pred)
+    fresh = staging()
+    for got, want, blank in zip(out, (ref.dist, ref.pred), fresh):
+        got, blank = np.asarray(got), np.asarray(blank)
+        assert_bit_equal(got[5:7, :8, :8], np.asarray(want), "bucket rows")
+        assert_bit_equal(got[5:7, 8:], blank[5:7, 8:], "inert rows")
+        assert_bit_equal(got[[0, 1, 2, 3, 4, 7]], blank[[0, 1, 2, 3, 4, 7]],
+                         "other slots")
+
+
 def test_dynamic_engine_no_stale_reads(rng):
     g = generate_np(rng, 36)
     eng = DynamicAPSP(g.h, with_pred=True, block_size=16)          # donate=True

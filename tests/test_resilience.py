@@ -109,6 +109,28 @@ def test_solve_batch_negative_cycle_in_one_graph(put, bucket_by_size):
 
 
 @HOST_AND_DEVICE
+def test_bucketed_negative_cycle_only_from_a_true_block(put):
+    """On the device frame only a graph's own diagonal can raise: negative
+    arcs without a cycle, padding slots (three graphs in a bucket of four)
+    and padding diagonals pass; one negative cycle names its graph."""
+    hs = [graph(n, s) for s, n in enumerate([5, 9, 12, 14, 20])]
+    for m in hs:
+        m[0, 1] = -3.0                    # a negative arc, no negative cycle
+        m[1, 0] = 4.0
+    r = solve_batch([put(m) for m in hs], bucket_by_size=True)
+    d = np.asarray(r.dist)
+    assert d.shape == (5, 20, 20) and d[1, 0, 1] == -3.0
+    assert (np.diagonal(d, axis1=1, axis2=2) == 0).all()
+    hs[3][2, 3], hs[3][3, 2] = -6.0, 2.0  # graph 3: edge 16, 3 graphs, 4 slots
+    with pytest.raises(NegativeCycleError, match=r"entry \(3, [23]\)"):
+        solve_batch([put(m) for m in hs], bucket_by_size=True)
+    r = solve_batch([put(m) for m in hs], bucket_by_size=True, validate=False)
+    diag = np.diagonal(np.asarray(r.dist), axis1=1, axis2=2)
+    assert (diag[3, [2, 3]] < 0).all()
+    assert (np.delete(diag, 3, axis=0) == 0).all()
+
+
+@HOST_AND_DEVICE
 def test_negative_cycle_check_ignores_padding_diagonal(put):
     d = np.zeros((2, 4, 4), np.float32)
     d[1, 3, 3] = -1.0                     # graph 1 holds 3 nodes: padding
