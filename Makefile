@@ -45,20 +45,20 @@ bench-check:    ## regression gate: median-of-3 fresh smoke vs committed baselin
 	$(PY) tools/bench_compare.py
 
 serve-apsp:     ## smoke the batched APSP serving loop
-	$(PY) -m repro.launch.serve --arch apsp --requests 32 --batch 16 --n-max 64
+	$(PY) -m repro.launch.serve --requests 32 --batch 16 --n-max 64
 
 serve-dynamic:  ## smoke the incremental (edge-update) serving loop
-	$(PY) -m repro.launch.serve --arch apsp --requests 32 --n-max 64 \
+	$(PY) -m repro.launch.serve --requests 32 --n-max 64 \
 		--mutate-rate 0.5 --graphs 2 --verify-every 8
 
 serve-chaos:    ## chaos smoke: seeded faults, zero poisoned answers, full recovery (non-zero exit on drift)
-	$(PY) -m repro.launch.serve --arch apsp --requests 48 --n-max 32 \
+	$(PY) -m repro.launch.serve --requests 48 --n-max 32 \
 		--mutate-rate 0.5 --graphs 3 --mutate-k 4 --verify-every 12 --seed 7 \
 		--fault-spec "nan:0.15,crash:0.1:3,latency:0.1:10,poison:0.1,mem:0.15:0.5" \
 		--deadline-ms 100 --mem-budget-mb 0.008 --backlog-watermark 4
 
 serve-chaos-correlated:  ## correlated chaos smoke: async executor + durable slots under backend loss, cache storms, crash-restore drills
-	$(PY) -m repro.launch.serve --arch apsp --requests 48 --n-max 32 \
+	$(PY) -m repro.launch.serve --requests 48 --n-max 32 \
 		--mutate-rate 0.5 --graphs 3 --mutate-k 4 --verify-every 12 --seed 7 \
 		--async-updates --durability-dir auto --checkpoint-every 2 \
 		--fault-spec "backend_loss:0.2:4,cache_storm:0.2:4,crash_restore:0.25,latency:0.05:5" \

@@ -1,5 +1,5 @@
 """The paper's future-work item, live: multi-device APSP on a fake 8-device
-mesh (same shard_map code the 512-chip dry-run compiles).
+mesh (the same shard_map code a real multi-chip mesh runs).
 
     PYTHONPATH=src python examples/apsp_distributed.py
 """

@@ -1,17 +1,13 @@
 from .checkpoint import (
-    CheckpointManager,
     load_checkpoint,
     load_engine_checkpoint,
-    restore_onto_mesh,
     save_checkpoint,
     save_engine_checkpoint,
 )
 
 __all__ = [
-    "CheckpointManager",
     "load_checkpoint",
     "load_engine_checkpoint",
-    "restore_onto_mesh",
     "save_checkpoint",
     "save_engine_checkpoint",
 ]

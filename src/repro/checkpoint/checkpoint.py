@@ -1,24 +1,16 @@
-"""Atomic pytree checkpoints with elastic (resharding) restore.
+"""Atomic pytree checkpoints.
 
 Layout per step:
     <dir>/step_000123/
         arrays.npz        key-path-flattened leaves
-        manifest.json     step, mesh shape/axes, data-stream cursor, leaf dtypes
+        manifest.json     step, leaf keys and dtypes, caller's extra metadata
 
 Write protocol (fault tolerant):
     1. write everything into  <dir>/.tmp_step_000123
     2. fsync, then os.replace -> step_000123       (atomic on POSIX)
     3. update <dir>/LATEST (tmp+replace again)
 A crash mid-write leaves only a .tmp_ directory, which restore ignores and
-the next save overwrites.  ``CheckpointManager`` runs saves on a background
-thread (double-buffered: device->host copy happens synchronously, disk I/O
-does not block the step loop) and keeps the last ``keep`` checkpoints.
-
-Elastic restore: arrays are stored unsharded (host gathered).  On restore,
-``restore_onto_mesh`` device_puts each leaf with the *target* mesh's
-NamedSharding — restarting 512-chip state onto a 256-chip mesh (or a
-differently-shaped mesh) is just a different spec tree.  Cross-pod-failure
-recovery = restore last step onto the surviving mesh.
+the next save overwrites.
 """
 
 from __future__ import annotations
@@ -26,15 +18,14 @@ from __future__ import annotations
 import json
 import os
 import shutil
-import threading
 from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
 
 __all__ = [
-    "save_checkpoint", "load_checkpoint", "restore_onto_mesh",
-    "CheckpointManager", "save_engine_checkpoint", "load_engine_checkpoint",
+    "save_checkpoint", "load_checkpoint",
+    "save_engine_checkpoint", "load_engine_checkpoint",
 ]
 
 _SEP = "/"
@@ -120,26 +111,6 @@ def load_checkpoint(directory: str, step: Optional[int] = None):
     return flat, manifest
 
 
-def restore_onto_mesh(flat: Dict[str, np.ndarray], example_tree, shardings=None):
-    """Rebuild ``example_tree``'s structure from ``flat``, placing each leaf
-    with the matching sharding (elastic restart onto any mesh)."""
-    paths, treedef = jax.tree_util.tree_flatten_with_path(example_tree)
-    shard_leaves = (
-        jax.tree_util.tree_leaves(shardings) if shardings is not None else [None] * len(paths)
-    )
-    leaves = []
-    for (path, example), sh in zip(paths, shard_leaves):
-        key = _SEP.join(_path_str(p) for p in path)
-        if key not in flat:
-            raise KeyError(f"checkpoint missing leaf {key!r}")
-        arr = flat[key]
-        if tuple(arr.shape) != tuple(example.shape):
-            raise ValueError(f"{key}: shape {arr.shape} != expected {example.shape}")
-        arr = arr.astype(example.dtype)
-        leaves.append(jax.device_put(arr, sh) if sh is not None else jax.device_put(arr))
-    return jax.tree_util.tree_unflatten(treedef, leaves)
-
-
 # -- durable engine snapshots (serving-tier restore path) -------------------
 #
 # A DynamicAPSP engine's recoverable state is its snapshot() dict
@@ -220,68 +191,3 @@ def load_engine_checkpoint(directory: str, step: Optional[int] = None) -> Dict[s
     out["h"] = flat["h"]
     out["version"] = int(meta["version"])
     return out
-
-
-class CheckpointManager:
-    """Background-threaded saver with retention.
-
-    ``save`` snapshots to host synchronously (cheap vs a training step) and
-    hands disk I/O to a worker thread; ``wait`` joins in-flight writes
-    (called before exit and before restore-after-failure)."""
-
-    def __init__(self, directory: str, keep: int = 3):
-        self.directory = directory
-        self.keep = keep
-        self._thread: Optional[threading.Thread] = None
-        self._error: Optional[BaseException] = None
-
-    def save(self, step: int, state, extra: Optional[dict] = None):
-        self.wait()
-        host_flat = _flatten(state)     # device->host before returning
-
-        def work():
-            try:
-                name = f"step_{step:09d}"
-                tmp = os.path.join(self.directory, f".tmp_{name}")
-                final = os.path.join(self.directory, name)
-                os.makedirs(tmp, exist_ok=True)
-                np.savez(os.path.join(tmp, "arrays.npz"), **host_flat)
-                manifest = {
-                    "step": step,
-                    "keys": sorted(host_flat.keys()),
-                    "dtypes": {k: str(v.dtype) for k, v in host_flat.items()},
-                    "extra": extra or {},
-                }
-                with open(os.path.join(tmp, "manifest.json"), "w") as f:
-                    json.dump(manifest, f)
-                    f.flush()
-                    os.fsync(f.fileno())
-                if os.path.exists(final):
-                    shutil.rmtree(final)
-                os.replace(tmp, final)
-                ltmp = os.path.join(self.directory, ".LATEST.tmp")
-                with open(ltmp, "w") as f:
-                    f.write(name)
-                os.replace(ltmp, os.path.join(self.directory, "LATEST"))
-                self._gc()
-            except BaseException as e:   # surfaced on next save/wait
-                self._error = e
-
-        os.makedirs(self.directory, exist_ok=True)
-        self._thread = threading.Thread(target=work, daemon=True)
-        self._thread.start()
-
-    def _gc(self):
-        steps = sorted(
-            d for d in os.listdir(self.directory) if d.startswith("step_")
-        )
-        for d in steps[: -self.keep]:
-            shutil.rmtree(os.path.join(self.directory, d), ignore_errors=True)
-
-    def wait(self):
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-        if self._error is not None:
-            err, self._error = self._error, None
-            raise err

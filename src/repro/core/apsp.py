@@ -36,7 +36,7 @@ zero, the diagonal is the semiring one (tropical: inf / 0).  The default
 
 Distributed execution lives in ``core/distributed.py`` and is selected via
 ``launch/apsp_run.py`` on a real mesh; the serving loop over batches lives
-in ``launch/serve.py --arch apsp``.
+in ``launch/serve.py``.
 """
 
 from __future__ import annotations

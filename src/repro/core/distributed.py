@@ -4,8 +4,8 @@ The distance matrix D (N, N) lives as a 2D block grid over the device mesh:
 rows sharded over ``row_axes`` (single-pod: ``("data",)``; multi-pod:
 ``("pod", "data")`` so the pod axis carries row-parallelism), columns over
 ``col_axes`` (``("model",)``).  Everything below is ``jax.shard_map`` with
-explicit collectives, so the dry-run HLO shows exactly the communication the
-roofline pass charges.
+explicit collectives, so the compiled HLO shows exactly the communication
+each solver pays.
 
 Three solvers:
 

@@ -8,9 +8,8 @@ j").  Two implementations:
 * ``reconstruct_path_jit``  — fixed-max-length ``lax.while_loop`` version that
   stays inside jit (returns a padded path + length), for on-device serving.
 
-``spd_features`` exposes the paper's solver to the GNN stack: landmark
-shortest-path-distance structural features (Graphormer-style), used by
-``examples/gnn_node_classification.py``.
+``spd_features`` exposes the paper's solver as landmark
+shortest-path-distance structural features (Graphormer-style) for GNNs.
 """
 
 from __future__ import annotations

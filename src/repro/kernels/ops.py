@@ -34,7 +34,7 @@ On TPU the Pallas kernels are the hot path.  On this CPU container the
 kernels are validated in ``interpret=True`` mode (Python-level execution) by
 the test suite, while runtime callers get the chunked pure-XLA fallback from
 ``repro.kernels.minplus_xla`` — same semantics (bit-exact, see the parity
-suite), fast on CPU, and the thing the dry-run lowers.
+suite), and fast on CPU.
 
 Backend selection (read at trace time — jit'd callers retrace only on shape
 change, so set the env before first use):

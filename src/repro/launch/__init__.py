@@ -1,8 +1,2 @@
-"""Launchers: mesh construction, dry-run, training and serving drivers.
-
-NOTE: do not import ``dryrun`` from here — it sets XLA_FLAGS at import time
-(512 host devices) and must only be imported as the entry module."""
-
-from .mesh import make_host_mesh, make_production_mesh
-
-__all__ = ["make_host_mesh", "make_production_mesh"]
+"""Launchers: the APSP serving drivers, the engine pool and the distributed
+APSP runner."""

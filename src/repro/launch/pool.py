@@ -1,6 +1,6 @@
 """Supervised engine pool: the resilient serving tier over ``DynamicAPSP``.
 
-``serve.py --arch apsp --mutate-rate`` used to hold bare engines forever
+``serve.py --mutate-rate`` used to hold bare engines forever
 and serve synchronously — the first NaN update, drifted engine, or memory
 squeeze either crashed the loop or silently served poison.  This module
 puts every persistent engine behind a health-checked :class:`EngineSlot`

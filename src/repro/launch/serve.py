@@ -1,15 +1,13 @@
-"""Serving driver: batched request loop over prefill + decode (LM),
-interest extraction + retrieval (MIND), or batched APSP over graph requests,
-on the reduced configs for CPU.
+"""Serving driver: batched APSP over a synthetic stream of graph requests.
 
 Demonstrates the production serving shape: one compiled program reused
-across requests, continuous batch slots with per-slot raggedness — kv_len
-per sequence for the LM, true graph size per slot for APSP.  The APSP mode
-packs incoming ragged graphs into fixed (G, N_max, N_max) inf-padded slots
-(padding is inert under (min, +)) so every batch hits the same compiled
-``solve_batch`` program; results are unpadded per graph before returning.
+across requests, continuous batch slots with per-slot raggedness (the true
+graph size per slot).  Incoming ragged graphs are packed into fixed
+(G, N_max, N_max) inf-padded slots (padding is inert under (min, +)) so every
+batch hits the same compiled ``solve_batch`` program; results are unpadded
+per graph before returning.
 
-With ``--mutate-rate > 0`` the APSP mode switches to the *incremental*
+With ``--mutate-rate > 0`` the driver switches to the *incremental*
 serving shape: a supervised pool (``repro.launch.pool``) of persistent
 ``repro.core.DynamicAPSP`` engines behind health-checked slots, serving an
 interleaved stream of edge-update batches (queued, coalesced, applied
@@ -20,13 +18,11 @@ backlogged).  ``--fault-spec`` turns on the deterministic chaos layer
 poisoned answer, or an unrecovered slot.
 
 Usage:
-    python -m repro.launch.serve --arch qwen2-1.5b --requests 4 --gen 16
-    python -m repro.launch.serve --arch mind --requests 8
-    python -m repro.launch.serve --arch apsp --requests 64 --batch 16 \\
+    python -m repro.launch.serve --requests 64 --batch 16 \\
         --n-max 128 --method squaring
-    python -m repro.launch.serve --arch apsp --requests 64 --n-max 128 \\
+    python -m repro.launch.serve --requests 64 --n-max 128 \\
         --mutate-rate 0.5 --graphs 4 --verify-every 16
-    python -m repro.launch.serve --arch apsp --requests 128 --n-max 64 \\
+    python -m repro.launch.serve --requests 128 --n-max 64 \\
         --mutate-rate 0.5 --graphs 3 --verify-every 16 \\
         --fault-spec nan:0.1,crash:0.08:3,poison:0.05 --deadline-ms 50
 """
@@ -39,69 +35,7 @@ import time
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.configs import get_arch
-from repro.models.transformer import decode_step, init_lm, prefill
-
-
-def serve_lm(arch_id: str, n_requests: int, gen_len: int, seed: int = 0) -> int:
-    arch = get_arch(arch_id)
-    cfg = arch.smoke_config()
-    key = jax.random.PRNGKey(seed)
-    params, _ = init_lm(key, cfg)
-    rng = np.random.default_rng(seed)
-
-    batch = max(2, min(4, n_requests))
-    prompt_len, max_len = 16, 16 + gen_len
-    jprefill = jax.jit(lambda p, t: prefill(p, t, cfg, max_len))
-    jdecode = jax.jit(lambda p, c, t: decode_step(p, c, t, cfg))
-
-    done = 0
-    t0 = time.time()
-    while done < n_requests:
-        toks = jnp.asarray(rng.integers(0, cfg.vocab, (batch, prompt_len)))
-        logits, cache = jprefill(params, toks)
-        out = [jnp.argmax(logits, -1)[:, None]]
-        for _ in range(gen_len - 1):
-            lg, cache = jdecode(params, cache, out[-1])
-            out.append(jnp.argmax(lg, -1)[:, None])
-        gen = jnp.concatenate(out, axis=1)
-        assert gen.shape == (batch, gen_len)
-        assert not bool(jnp.any(jnp.isnan(lg)))
-        done += batch
-        print(f"[serve] batch of {batch}: prompt {prompt_len} -> +{gen_len} tokens "
-              f"(sample: {np.asarray(gen[0,:8]).tolist()})")
-    dt = time.time() - t0
-    print(f"[done] {done} requests, {done * gen_len / dt:.1f} tok/s (CPU smoke)")
-    return 0
-
-
-def serve_mind(n_requests: int, seed: int = 0) -> int:
-    from repro.data import mind_batch_stream
-    from repro.models.mind import init_mind, retrieval_scores, serve_user
-
-    arch = get_arch("mind")
-    cfg = arch.smoke_config()
-    params, _ = init_mind(jax.random.PRNGKey(seed), cfg)
-    stream = mind_batch_stream(
-        batch=n_requests, n_items=cfg.n_items, hist_len=cfg.hist_len,
-        n_profile_feats=cfg.n_profile_feats, profile_bag_len=cfg.profile_bag_len,
-        n_interests=cfg.n_interests, n_negatives=cfg.n_negatives, seed=seed,
-    )
-    batch = {k: jnp.asarray(v) for k, v in next(stream).items() if k != "step"}
-    interests = jax.jit(lambda p, b: serve_user(p, b, cfg))(params, batch)
-    print(f"[serve] {n_requests} users -> interests {interests.shape}")
-
-    one = {k: v[:1] for k, v in batch.items()}
-    one["cand_ids"] = jnp.arange(cfg.n_items, dtype=jnp.int32)
-    vals, ids = jax.jit(
-        lambda p, b: retrieval_scores(p, b, cfg, top_k=10)
-    )(params, one)
-    print(f"[retrieval] top-10 of {cfg.n_items}: ids={np.asarray(ids).tolist()}")
-    return 0
-
 
 #: semirings the synthetic tropical request stream can be recast into.
 RECASTABLE = ("tropical", "bottleneck", "reliability", "boolean")
@@ -464,98 +398,91 @@ def _recast_edge_weights(w: np.ndarray, semiring: str) -> np.ndarray:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=4)
-    ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--batch", type=int, default=16,
-                    help="apsp: graph slots per serving cycle")
+                    help="graph slots per serving cycle")
     ap.add_argument("--n-max", type=int, default=128,
-                    help="apsp: padded graph edge (compiled shape)")
+                    help="padded graph edge (compiled shape)")
     ap.add_argument("--method", default="squaring",
-                    help="apsp: solver (see repro.core.METHODS)")
+                    help="solver (see repro.core.METHODS)")
     ap.add_argument("--with-pred", action="store_true",
-                    help="apsp: also compute predecessor matrices")
+                    help="also compute predecessor matrices")
     ap.add_argument("--semiring", default="tropical",
-                    help="apsp: path semiring (see repro.core.SEMIRINGS)")
+                    help="path semiring (see repro.core.SEMIRINGS)")
     ap.add_argument("--mutate-rate", type=float, default=0.0,
-                    help="apsp: fraction of requests that are edge-update "
+                    help="fraction of requests that are edge-update "
                          "batches against persistent graph state (> 0 "
                          "selects the incremental DynamicAPSP serving mode)")
     ap.add_argument("--graphs", type=int, default=4,
-                    help="apsp dynamic mode: persistent graph count")
+                    help="dynamic mode: persistent graph count")
     ap.add_argument("--mutate-k", type=int, default=8,
-                    help="apsp dynamic mode: max edges per update batch")
+                    help="dynamic mode: max edges per update batch")
     ap.add_argument("--verify-every", type=int, default=0,
-                    help="apsp dynamic mode: differentially check an engine "
+                    help="dynamic mode: differentially check an engine "
                          "against a cold solve every N requests (0 = off; "
                          "detected drift exits non-zero)")
     ap.add_argument("--fault-spec", default="",
-                    help="apsp dynamic mode: chaos layer, e.g. "
+                    help="dynamic mode: chaos layer, e.g. "
                          "'nan:0.1,crash:0.08:3,latency:0.1:20,poison:0.05,"
                          "mem:0.1:0.5' (see repro.launch.faults)")
     ap.add_argument("--deadline-ms", type=float, default=0.0,
-                    help="apsp dynamic mode: per-query deadline; a miss is "
+                    help="dynamic mode: per-query deadline; a miss is "
                          "answered from the last-known-good snapshot (0 = off)")
     ap.add_argument("--mem-budget-mb", type=float, default=0.0,
-                    help="apsp dynamic mode: device-state budget; admissions "
+                    help="dynamic mode: device-state budget; admissions "
                          "beyond it evict LRU slots (0 = unlimited)")
     ap.add_argument("--backlog-watermark", type=int, default=8,
-                    help="apsp dynamic mode: pending update batches above "
+                    help="dynamic mode: pending update batches above "
                          "which queries are shed to snapshots")
     ap.add_argument("--max-retries", type=int, default=2,
-                    help="apsp dynamic mode: transient apply failures "
+                    help="dynamic mode: transient apply failures "
                          "retried (with backoff) before quarantine")
     ap.add_argument("--async-updates", action="store_true",
-                    help="apsp dynamic mode: apply update batches on the "
+                    help="dynamic mode: apply update batches on the "
                          "background executor; queries read published "
                          "snapshots and never wait on an in-flight pass")
     ap.add_argument("--executor-workers", type=int, default=1,
-                    help="apsp dynamic mode: background drain threads "
+                    help="dynamic mode: background drain threads "
                          "(with --async-updates)")
     ap.add_argument("--reader-workers", type=int, default=0,
-                    help="apsp dynamic mode: deadline-reader sizing for the "
+                    help="dynamic mode: deadline-reader sizing for the "
                          "sync path (0 = one dedicated worker per slot)")
     ap.add_argument("--durability-dir", default="",
-                    help="apsp dynamic mode: per-slot write-ahead journal + "
+                    help="dynamic mode: per-slot write-ahead journal + "
                          "atomic engine checkpoints under this directory "
                          "('auto' = fresh temp dir); required by the "
                          "crash_restore chaos drill")
     ap.add_argument("--checkpoint-every", type=int, default=0,
-                    help="apsp dynamic mode: checkpoint a durable slot every "
+                    help="dynamic mode: checkpoint a durable slot every "
                          "N successful drains (0 = only the build-time "
                          "checkpoint)")
     args = ap.parse_args(argv)
     from repro.caches import enable_compile_cache
 
     enable_compile_cache()
-    if args.arch == "mind":
-        return serve_mind(args.requests, args.seed)
-    if args.arch == "apsp":
-        if args.mutate_rate > 0.0:
-            return serve_apsp_dynamic(
-                args.requests, n_max=args.n_max, graphs=args.graphs,
-                mutate_rate=args.mutate_rate, mutate_k=args.mutate_k,
-                method=args.method, with_pred=args.with_pred,
-                semiring=args.semiring, verify_every=args.verify_every,
-                seed=args.seed, fault_spec=args.fault_spec,
-                deadline_ms=args.deadline_ms,
-                mem_budget_mb=args.mem_budget_mb,
-                backlog_watermark=args.backlog_watermark,
-                max_retries=args.max_retries,
-                async_updates=args.async_updates,
-                executor_workers=args.executor_workers,
-                reader_workers=args.reader_workers,
-                durability_dir=args.durability_dir,
-                checkpoint_every=args.checkpoint_every,
-            )
-        return serve_apsp(
-            args.requests, batch=args.batch, n_max=args.n_max,
+    if args.mutate_rate > 0.0:
+        return serve_apsp_dynamic(
+            args.requests, n_max=args.n_max, graphs=args.graphs,
+            mutate_rate=args.mutate_rate, mutate_k=args.mutate_k,
             method=args.method, with_pred=args.with_pred,
-            semiring=args.semiring, seed=args.seed,
+            semiring=args.semiring, verify_every=args.verify_every,
+            seed=args.seed, fault_spec=args.fault_spec,
+            deadline_ms=args.deadline_ms,
+            mem_budget_mb=args.mem_budget_mb,
+            backlog_watermark=args.backlog_watermark,
+            max_retries=args.max_retries,
+            async_updates=args.async_updates,
+            executor_workers=args.executor_workers,
+            reader_workers=args.reader_workers,
+            durability_dir=args.durability_dir,
+            checkpoint_every=args.checkpoint_every,
         )
-    return serve_lm(args.arch, args.requests, args.gen, args.seed)
-
+    return serve_apsp(
+        args.requests, batch=args.batch, n_max=args.n_max,
+        method=args.method, with_pred=args.with_pred,
+        semiring=args.semiring, seed=args.seed,
+    )
 
 if __name__ == "__main__":
     sys.exit(main())

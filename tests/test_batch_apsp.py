@@ -8,6 +8,7 @@ import pytest
 from conftest import np_floyd_warshall
 from oracle import generate
 from repro.core import (
+    SEMIRINGS,
     generate_batch,
     generate_np,
     pad_batch,
@@ -66,12 +67,19 @@ def test_batch_pred_matches_and_is_valid(method, ragged_graphs):
             assert abs(path_cost(g.h, path) - d[a, b]) < 1e-4
 
 
-@pytest.mark.parametrize("method", ["squaring", "blocked_fw"])
-def test_bucketed_equals_single_stack(method, ragged_graphs):
-    hs = [g.h for g in ragged_graphs]
-    a = solve_batch(hs, method=method, with_pred=True, **METHOD_KW[method])
-    b = solve_batch(hs, method=method, with_pred=True, bucket_by_size=True,
-                    **METHOD_KW[method])
+# edges 8, 16, 32, 32 and 64: four buckets, one of them shared
+BUCKET_SIZES = [5, 9, 17, 30, 33]
+
+
+@pytest.mark.parametrize("semiring", sorted(SEMIRINGS))
+@pytest.mark.parametrize("method", sorted(METHOD_KW))
+def test_bucketed_equals_single_stack(method, semiring):
+    rng = np.random.default_rng(5)
+    hs = [generate(rng, k, semiring) for k in BUCKET_SIZES]
+    kw = dict(method=method, with_pred=True, semiring=semiring,
+              **METHOD_KW[method])
+    a = solve_batch(hs, **kw)
+    b = solve_batch(hs, bucket_by_size=True, **kw)
     assert np.array_equal(np.asarray(a.dist), np.asarray(b.dist))
     assert np.array_equal(np.asarray(a.pred), np.asarray(b.pred))
     assert np.array_equal(a.sizes, b.sizes)

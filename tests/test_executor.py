@@ -17,6 +17,7 @@ import threading
 import numpy as np
 import pytest
 
+from oracle import generate
 from repro.core import DynamicAPSP
 from repro.core.dynamic import UpdateJournal
 from repro.core.graphgen import generate_np
@@ -124,24 +125,41 @@ def test_journal_rejected_batch_never_journaled(tmp_path):
 # engine checkpoints
 # ---------------------------------------------------------------------------
 
+# update weights in each semiring's domain, from tropical costs in [0.5, 8)
+DOMAIN_WEIGHTS = {
+    "tropical": lambda w: w,
+    "bottleneck": lambda w: w,
+    "reliability": lambda w: (1.0 / (1.0 + w)).astype(np.float32),
+    "boolean": np.ones_like,
+}
+
+
 @pytest.mark.parametrize("with_pred", [False, True])
-def test_engine_checkpoint_roundtrip_bit_exact(tmp_path, with_pred):
+@pytest.mark.parametrize("semiring", sorted(DOMAIN_WEIGHTS))
+def test_engine_checkpoint_roundtrip_bit_exact(tmp_path, semiring, with_pred):
     n = 12
-    eng = DynamicAPSP(graph(n), with_pred=with_pred)
+    h = (graph(n) if semiring == "tropical"
+         else generate(np.random.default_rng(0), n, semiring))
+    eng = DynamicAPSP(h, with_pred=with_pred, semiring=semiring)
     u, v, w = updates(n, 4, seed=2)
-    eng.update(u, v, w)
+    eng.update(u, v, DOMAIN_WEIGHTS[semiring](w))
     save_engine_checkpoint(str(tmp_path), eng)
     st = load_engine_checkpoint(str(tmp_path))
     assert st["version"] == eng.version
     assert st["n"] == n and st["with_pred"] is with_pred
+    assert st["semiring"] == semiring
     np.testing.assert_array_equal(st["dist"], np.asarray(eng.dist))
     np.testing.assert_array_equal(st["h"], eng.h)
     if with_pred:
         np.testing.assert_array_equal(st["pred"], np.asarray(eng.pred))
     # the loaded state boots an engine with no cold solve, bit-identical
-    twin = DynamicAPSP(st["h"], with_pred=with_pred, state=st)
+    twin = DynamicAPSP(st["h"], with_pred=with_pred, semiring=st["semiring"],
+                       state=st)
     assert twin.version == eng.version
     np.testing.assert_array_equal(np.asarray(twin.dist), np.asarray(eng.dist))
+    if with_pred:
+        np.testing.assert_array_equal(np.asarray(twin.pred),
+                                      np.asarray(eng.pred))
 
 
 def test_engine_checkpoint_roundtrip_bfloat16(tmp_path):
