@@ -26,6 +26,19 @@ share of the 2n^3 total).  col' costs n·B^2 per round, the col-panel
 product itself.  The j axis is "arbitrary" because the col' scratch is
 carried across it; g and i are "parallel".
 
+Tiles and register bands.  The fold holds a band of 32 f32 accumulator
+vregs (``minplus._band_rows``).  Each k lane-broadcasts one x column per
+8-row group of the band, a permute through the cross-lane unit, and adds
+it to every vreg of that group; a y row is sublane-replicated once per
+128 lanes.  ``fw_round`` takes 1024-lane tiles where n allows it
+(``_round_tile``): a 32 x 1024 band pays 4 lane broadcasts per k where
+the 64 x 512 band of a 512-lane tile pays 8, for the same 64 VALU ops,
+and the permutes set the fold's pace.  ``fw_round_pred`` keeps 512-lane
+tiles (``_col_tile``): its witness plane halves the band to 32 x 512, and
+its 4 VALU ops per relaxation (add, compare, min, select) bound it.
+Where no 128-multiple divides n (zinc's 16-64-wide buckets) both take a
+whole-row tile.
+
 Bit-exactness: the candidate sums are identical to the chunked-XLA
 fallback (``minplus_xla.fw_round_xla``) — same closure fold, same
 ``col ⊗ A*`` association — and a selective ⊕ over the same candidate set
@@ -65,12 +78,41 @@ from .minplus import _band_rows, _fold, _row_group, _windows
 __all__ = ["fw_round_pallas", "fw_round_pred_pallas", "PALLAS_BUILDERS"]
 
 
+_WIDE_TILE = 1024
+# VMEM the stripe grid's three double-buffered (B, bn) tiles (row panel,
+# accumulate operand, output), counted in the compute dtype, may take:
+# 12 MiB of the 16 MiB a v5e kernel gets by default, the rest left to the
+# (B, B) pivot, col-panel and col′ blocks.  At 1024 lanes that admits every
+# B up to 512, in f32 and in bf16 storage alike.
+_TILE_VMEM = 12 * 2**20
+
+
 def _col_tile(n: int) -> int:
-    """Output tile width: the widest 128-multiple dividing n, else all of n."""
+    """Output tile width of ``fw_round_pred``: the widest 128-multiple of
+    at most 512 dividing n, else all of n (zinc's 16-64-wide buckets).
+
+    The fold's row band holds 32 f32 vregs (16 with the witness plane,
+    ``minplus._band_rows``), so at 512 lanes ``fw_round_pred``'s band is
+    32 rows: 4 lane broadcasts of an x column per k for 16 accumulator
+    vregs, beside 4 VALU ops per relaxation that bound it instead."""
     for bn in (512, 256, 128):
         if n % bn == 0:
             return bn
     return n
+
+
+def _round_tile(n: int, b: int, itemsize: int) -> int:
+    """Output tile width of ``fw_round``: 1024 lanes where n allows it and
+    its tiles fit VMEM, else ``_col_tile``.
+
+    Each k of the stripe fold lane-broadcasts one x column per 8-row group
+    (a permute through the cross-lane unit) and adds it to every
+    accumulator vreg of that group.  A (64, 512) band pays 8 broadcasts
+    for 32 vregs, a (32, 1024) band 4: the same VALU work for half the
+    permutes, which set the fold's pace, not the VALU."""
+    if n % _WIDE_TILE == 0 and 6 * b * _WIDE_TILE * itemsize <= _TILE_VMEM:
+        return _WIDE_TILE
+    return _col_tile(n)
 
 
 @functools.partial(
@@ -98,7 +140,7 @@ def fw_round_pallas(
     assert n == n2 and n % b == 0, (d.shape, b)
     storage = d.dtype
     cd = jnp.float32 if storage == jnp.bfloat16 else storage
-    bn = _col_tile(n)
+    bn = _round_tile(n, b, jnp.dtype(cd).itemsize)
     lc = _window(b)
 
     pivot = jax.lax.dynamic_slice(dd, (0, o, o), (g, b, b))
@@ -212,8 +254,8 @@ def fw_round_pred_pallas(
 
     ``p`` holds global node ids (-1: no path).  Returns ``(d', p')``: the
     pivot closure dispatch (``fw_block_pred``) plus one row-stripe grid,
-    ``fw_round_pallas``'s grid and tiles with the int32 witness tiles
-    beside the distance tiles.
+    ``fw_round_pallas``'s grid on ``_col_tile``'s tiles, with the int32
+    witness tiles beside the distance tiles.
     """
     sr = semiring
     b = block_size

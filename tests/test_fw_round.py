@@ -21,6 +21,9 @@ from repro.core import generate_np, solve, validate_tree
 from repro.core.blocked_fw import blocked_fw, blocked_fw_batch
 from repro.core.rkleene import padded_size, rkleene, split_point
 from repro.kernels import ops
+from repro.kernels.fw_round import (_col_tile, _round_tile, fw_round_pallas,
+                                    fw_round_pred_pallas)
+from repro.kernels.minplus_xla import fw_round_xla
 
 
 def _with_backend(monkeypatch, name):
@@ -97,6 +100,89 @@ def test_fw_round_kernel_batched(rng, monkeypatch):
     _with_backend(monkeypatch, "xla")
     ref = np.asarray(ops.fw_round(hs, jnp.int32(16), block_size=16))
     assert_bit_equal(got, ref, "batched fw_round")
+
+
+def _stripe_grids(fn, *specs):
+    """Grids of the Pallas calls ``fn`` traces to, outermost first."""
+    def calls(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield tuple(e.params["grid_mapping"].grid)
+            for v in e.params.values():
+                sub = getattr(v, "jaxpr", None)
+                if sub is not None:
+                    yield from calls(getattr(sub, "jaxpr", sub))
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in specs]
+    return list(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("n,b,want", [
+    (8192, 256, 1024),                    # road8k.cold's own shape
+    (16384, 128, 1024), (16384, 256, 1024), (16384, 512, 1024),
+    (16384, 1024, 512),                   # the wide tiles would not fit VMEM
+    (2048, 256, 1024), (1536, 256, 512), (384, 128, 128),
+    (16, 16, 16), (32, 32, 32), (64, 64, 64),   # zinc's bucket edges
+])
+def test_round_tile_width(n, b, want):
+    assert _round_tile(n, b, 4) == want
+    # the stripe grid fw_round_pallas builds walks n // want column tiles
+    f32, i32 = jnp.float32, jnp.int32
+    grids = _stripe_grids(
+        lambda d, o: fw_round_pallas(d, o, block_size=b),
+        ((n, n), f32), ((), i32))
+    assert grids[-1] == (1, n // b, n // want)
+
+
+@pytest.mark.parametrize("n,b,want", [
+    (8192, 256, 512), (16384, 256, 512), (2048, 256, 512),
+    (16, 16, 16), (32, 32, 32), (64, 64, 64),
+])
+def test_route_round_keeps_its_tile(n, b, want):
+    # fw_round_pred keeps the 512-lane tile: its witness plane halves the
+    # band, and add, compare, min and select bound it, not the permutes
+    assert _col_tile(n) == want
+    f32, i32 = jnp.float32, jnp.int32
+    grids = _stripe_grids(
+        lambda d, p, o: fw_round_pred_pallas(d, p, o, block_size=b),
+        ((n, n), f32), ((n, n), i32), ((), i32))
+    assert grids[-1] == (1, n // b, n // want)
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_fw_round_wide_tile_parity_interpret_vs_xla(g, rng):
+    """At n=2048, B=256 the stripe grid takes two 1024-lane tiles a stripe
+    and folds 32-row bands; the pivot (t=5) sits in the second tile.  The
+    kernel agrees bit for bit with the chunked-XLA fallback on float
+    weights, as a single matrix and as a stack."""
+    n, b, o = 2048, 256, 5 * 256
+    a = rng.uniform(1, 100, size=(g, n, n)).astype(np.float32)
+    h = np.where(rng.uniform(size=(g, n, n)) < 0.99, np.inf, a)
+    for x in h:
+        np.fill_diagonal(x, 0.0)
+    d = jnp.asarray(h if g > 1 else h[0])
+    got = fw_round_pallas(d, jnp.int32(o), block_size=b, interpret=True)
+    ref = jax.vmap(lambda x: fw_round_xla(x, jnp.int32(o), block_size=b))(
+        jnp.asarray(h))
+    assert_bit_equal(np.asarray(got), np.asarray(ref if g > 1 else ref[0]),
+                     f"wide-tile fw_round, g={g}")
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_blocked_fw_wide_tile_matches_oracle(g, rng, monkeypatch):
+    # a whole solve on the Pallas kernels (interpret) at n=1024, B=256,
+    # where each round's stripe is one 1024-lane tile: four rounds
+    _with_backend(monkeypatch, "interpret")
+    hs = [generate_np(rng, 1024).h for _ in range(g)]
+    if g == 1:
+        d, _ = blocked_fw(jnp.asarray(hs[0]), block_size=256,
+                          round_mode="fused")
+        got = [np.asarray(d)]
+    else:
+        d, _ = blocked_fw_batch(jnp.asarray(np.stack(hs)), block_size=256,
+                                round_mode="fused")
+        got = list(np.asarray(d))
+    for i, (x, h) in enumerate(zip(got, hs)):
+        assert_bit_equal(x, np_closure(h).astype(np.float32), f"graph {i}")
 
 
 def test_blocked_fw_end_to_end_backend_parity(rng, monkeypatch):

@@ -104,6 +104,10 @@ def _kernels():
         "fw_round": (
             lambda d, o: fw_round_pallas(d, o, block_size=BLOCK),
             [((N, N), f32), ((), i32)]),
+        # road8k.cold's own round: 1024-lane tiles, 32-row bands
+        "fw_round_n8192": (
+            lambda d, o: fw_round_pallas(d, o, block_size=BLOCK),
+            [((8192, 8192), f32), ((), i32)]),
         "fw_round_batched": (
             lambda d, o: fw_round_pallas(d, o, block_size=128),
             [((256, 128, 128), f32), ((), i32)]),
